@@ -41,6 +41,8 @@ def bench_meta() -> dict:
     "what produced this number" when comparing across PRs/machines."""
     import jaxlib
 
+    from repro.kernels.ops import resolve_interpret
+
     devices = jax.devices()
     return {
         "git_sha": _git_sha(),
@@ -49,9 +51,10 @@ def bench_meta() -> dict:
         "device_kind": devices[0].device_kind if devices else None,
         "device_count": jax.device_count(),
         "platform": jax.default_backend(),
-        # Pallas kernels run under pl.pallas_call(interpret=...) off-TPU —
-        # timing columns from interpret-mode runs are shapes, not speeds
-        "interpret_mode": jax.default_backend() != "tpu",
+        # Pallas kernels run under pl.pallas_call(interpret=...) on the
+        # CPU — timing columns from interpret-mode runs are shapes, not
+        # speeds
+        "interpret_mode": resolve_interpret(),
         "timestamp_utc": datetime.datetime.now(
             datetime.timezone.utc).isoformat(timespec="seconds"),
     }

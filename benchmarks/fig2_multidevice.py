@@ -1,10 +1,11 @@
 """Figure 2: training speedup from additional devices.
 
-Runs the distributed MLL step on 1/2/4/8 fake CPU devices (subprocess so
-the parent keeps one device). Wall-clock on fake CPU devices includes real
-thread-level parallelism across the partitioned MVM, so the SHAPE of the
-scaling curve is observable, if noisy; the dry-run collective analysis is
-the production-scale evidence.
+Runs the distributed MLL step on 1/2/4/8 devices. On a TPU host the cells
+run in this process over subsets of `jax.devices()`: a chip belongs to one
+process, and the parent already holds them. On the CPU each cell is a
+child process with that many fake host devices (so the parent keeps one);
+wall-clock there includes real thread-level parallelism across the
+partitioned MVM, so the SHAPE of the scaling curve is observable, if noisy.
 
 Beyond the paper's 1-D curve, the grid carries a 2-D (rows x cols) row per
 device count plus an overlap ablation column: the ring-pipelined chunked
@@ -18,62 +19,73 @@ import json
 import os
 import subprocess
 import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from .common import write_rows
 
-SCRIPT = r"""
-import os, sys, time, json
-os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={sys.argv[1]}"
-import jax, jax.numpy as jnp, numpy as np
-from repro.core import init_params
-from repro.core.distributed import (DistMLLConfig, make_geometry,
-                                    make_mll_value_and_grad, replicate,
-                                    shard_vector)
-ndev = int(sys.argv[1])
-mode = sys.argv[2]
-overlap = sys.argv[3] == "overlap"
-n, d = 4096, 8
-rng = np.random.default_rng(0)
-X = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
-y = jnp.asarray(rng.normal(size=(n,)), jnp.float32)
-params = init_params(noise=0.2, dtype=jnp.float32)
-if mode == "2d" and ndev > 1:
-    mesh = jax.make_mesh((ndev // 2, 2), ("data", "model"))
-else:
-    mesh = jax.make_mesh((ndev,), ("data",))
-geom = make_geometry(mesh, n, d, mode=mode, row_block=256, overlap=overlap)
-cfg = DistMLLConfig(precond_rank=50, num_probes=8, max_cg_iters=20, cg_tol=1.0)
-vg = make_mll_value_and_grad(mesh, geom, cfg)
-args = (replicate(mesh, X), shard_vector(mesh, geom, y),
-        replicate(mesh, params), jax.random.PRNGKey(0))
-out = vg(*args); jax.block_until_ready(out[0])   # compile
-t0 = time.time()
-reps = 3
-for _ in range(reps):
-    out = vg(*args)
-    jax.block_until_ready(out[0])
-print(json.dumps({"ndev": ndev, "step_s": (time.time() - t0) / reps}))
-"""
+DEVICE_COUNTS = (1, 2, 4, 8)
 
 
-def _cell(env, ndev, mode, overlap):
+def step_time(ndev: int, mode: str, overlap: bool, reps: int = 3) -> float:
+    """Seconds per distributed MLL value+grad step on the first `ndev`
+    devices."""
+    from repro.core import init_params
+    from repro.core.distributed import (DistMLLConfig, make_geometry,
+                                        make_mll_value_and_grad, replicate,
+                                        shard_vector)
+    from repro.launch.mesh import make_host_mesh
+
+    n, d = 4096, 8
+    rng = np.random.default_rng(0)
+    X = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    y = jnp.asarray(rng.normal(size=(n,)), jnp.float32)
+    params = init_params(noise=0.2, dtype=jnp.float32)
+    if mode == "2d" and ndev > 1:
+        mesh = make_host_mesh(data=ndev // 2, model=2)
+    else:
+        mesh = make_host_mesh(data=ndev, model=1)
+    geom = make_geometry(mesh, n, d, mode=mode, row_block=256,
+                         overlap=overlap)
+    cfg = DistMLLConfig(precond_rank=50, num_probes=8, max_cg_iters=20,
+                        cg_tol=1.0)
+    vg = make_mll_value_and_grad(mesh, geom, cfg)
+    args = (replicate(mesh, X), shard_vector(mesh, geom, y),
+            replicate(mesh, params), jax.random.PRNGKey(0))
+    jax.block_until_ready(vg(*args))  # compile
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        jax.block_until_ready(vg(*args))
+    return (time.perf_counter() - t0) / reps
+
+
+def _cell(ndev: int, mode: str, overlap: bool) -> float:
+    if jax.default_backend() == "tpu":
+        return step_time(ndev, mode, overlap)
+    env = dict(os.environ, PYTHONPATH="src",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}")
     out = subprocess.run(
-        [sys.executable, "-c", SCRIPT, str(ndev), mode,
-         "overlap" if overlap else "serial"],
-        capture_output=True, text=True, env=env, timeout=1200)
+        [sys.executable, "-m", "benchmarks.fig2_multidevice", "--cell",
+         str(ndev), mode, "overlap" if overlap else "serial"],
+        capture_output=True, text=True, env=env, timeout=1200, check=True)
     line = [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
     return json.loads(line)["step_s"]
 
 
 def run():
+    counts = DEVICE_COUNTS
+    if jax.default_backend() == "tpu":
+        counts = tuple(c for c in counts if c <= len(jax.devices()))
     rows = []
     base = None
-    env = dict(os.environ, PYTHONPATH="src")
-    for ndev in (1, 2, 4, 8):
-        s_1d = _cell(env, ndev, "1d", False)
+    for ndev in counts:
+        s_1d = _cell(ndev, "1d", False)
         # 2-D needs a model axis; on 1 device it degenerates to 1-D
-        s_2d = _cell(env, ndev, "2d", False) if ndev > 1 else s_1d
-        s_2d_ov = _cell(env, ndev, "2d", True) if ndev > 1 else s_1d
+        s_2d = _cell(ndev, "2d", False) if ndev > 1 else s_1d
+        s_2d_ov = _cell(ndev, "2d", True) if ndev > 1 else s_1d
         if base is None:
             base = s_1d
         rows.append([ndev, round(s_1d, 3), round(base / s_1d, 2),
@@ -88,4 +100,10 @@ def run():
 
 
 if __name__ == "__main__":
-    run()
+    if sys.argv[1:2] == ["--cell"]:
+        # child of a CPU run: XLA_FLAGS gave this process the fake devices
+        ndev, mode, ov = int(sys.argv[2]), sys.argv[3], sys.argv[4]
+        print(json.dumps({"ndev": ndev, "step_s": step_time(
+            ndev, mode, ov == "overlap")}))
+    else:
+        run()

@@ -32,6 +32,9 @@ def main():
                     help="single-seed Table 1")
     args = ap.parse_args()
 
+    from repro.launch.runtime import setup_runtime
+
+    setup_runtime()
     from . import (ablation_kernels, ablation_sparsity, ablation_tolerance,
                    ablation_warmstart, fig1_fig5_init, fig2_multidevice,
                    fig3_inducing, fig4_subset, roofline_report,

@@ -23,11 +23,14 @@ def main(pattern="experiments/dryrun/*.json"):
             from repro.configs.gp_exact_1m import CONFIG as cfg
             if r.get("gp_mode"):
                 cfg = cfg._replace(mode=r["gp_mode"])
+            cfg = cfg._replace(compute_dtype=r.get("compute_dtype"))
         else:
             cfg = get_arch(cell.arch)
         mf = rl.model_flops_for(cfg, cell)
+        # the dtype rule of repro.launch.dryrun
+        cdt = getattr(cfg, "compute_dtype", "bf16") or "float32"
         roof = rl.analyze(r["cost"], {"total": r["collectives"]["total"]},
-                          mf, r["n_devices"])
+                          mf, r["n_devices"], compute_dtype=cdt)
         r["roofline"] = roof._asdict()
         json.dump(r, open(path, "w"), indent=1, default=str)
         print(f"{path.split('/')[-1]}: useful={roof.useful_ratio:.3f} "

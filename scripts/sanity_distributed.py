@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import dense_khat, dense_mll, init_params, parse_kernel
 from repro.core.distributed import (
@@ -51,7 +51,7 @@ def full_oracle_checks():
 
         f = jax.jit(shard_map(local_mvm, mesh=mesh,
                               in_specs=(P(), geom.vector_pspec()),
-                              out_specs=geom.vector_pspec(), check_rep=False))
+                              out_specs=geom.vector_pspec(), check_vma=False))
         out = f(replicate(mesh, X), shard_vector(mesh, geom, V))
         print(f"[{mode}] dist kmvm err:", float(jnp.max(jnp.abs(out - Khat @ V))))
 
@@ -62,7 +62,7 @@ def full_oracle_checks():
             return pre.L_local, pre.chol_inner
         g = jax.jit(shard_map(local_pc, mesh=mesh, in_specs=(P(),),
                               out_specs=(geom.vector_pspec(), P()),
-                              check_rep=False))
+                              check_vma=False))
         L_dist, chol = g(replicate(mesh, X))
         L_ref = pivoted_cholesky("matern32", X, params, 40)
         # pivoted cholesky columns are sign/order-deterministic -> exact match
@@ -114,7 +114,7 @@ def blocksparse_2d_minifit():
     f = jax.jit(shard_map(
         lambda Xr, Vl: dist_blocksparse_kmvm(geom, spec, Xr, Vl, params, plan),
         mesh=mesh, in_specs=(P(), geom.vector_pspec()),
-        out_specs=geom.vector_pspec(), check_rep=False))
+        out_specs=geom.vector_pspec(), check_vma=False))
     out = np.asarray(f(replicate(mesh, Xp), shard_vector(mesh, geom, Vp)))
     ref = np.asarray(dense_khat(spec, Xs, params)) @ np.asarray(V)
     err = float(np.abs(out[:n] - ref).max())
@@ -164,7 +164,7 @@ def nondivisible_padded_case():
             f = jax.jit(shard_map(local_mvm, mesh=mesh,
                                   in_specs=(P(), geom.vector_pspec()),
                                   out_specs=geom.vector_pspec(),
-                                  check_rep=False))
+                                  check_vma=False))
             out = np.asarray(f(replicate(mesh, Xp),
                                shard_vector(mesh, geom, Vp)))
             err = float(np.abs(out[:n] - np.asarray(Khat @ V)).max())

@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro import obs
 
@@ -639,7 +639,7 @@ class ShardedOperator(KernelOperator):
         <A[B_i], K(B_i, C_j) V[C_j]> and its gradient, evaluated blockwise
         with bounded memory by `quad_form_partials`. The caller psums the
         results. (AD through the forward would over-count by the device
-        count: under shard_map(check_rep=False) the transpose of a trailing
+        count: under shard_map(check_vma=False) the transpose of a trailing
         psum is psum again.)
         """
         geom = self.geom
@@ -819,7 +819,7 @@ def make_mll_value_and_grad(mesh: Mesh, geom: DistGeometry, cfg: DistMLLConfig):
         local_fn, mesh=mesh,
         in_specs=(P(), vec, P(), P()),
         out_specs=(P(), (P(), P(), P(), P()), P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 
@@ -921,15 +921,15 @@ def make_warm_mll_step(mesh: Mesh, geom: DistGeometry, cfg: DistMLLConfig,
     out_specs = (rep, aux_specs, rep, state_specs)
     cold = jax.jit(shard_map(
         local_cold, mesh=mesh, in_specs=(P(), vec, P(), P()),
-        out_specs=out_specs, check_rep=False))
+        out_specs=out_specs, check_vma=False))
     refresh = jax.jit(shard_map(
         local_refresh, mesh=mesh,
         in_specs=(P(), vec, P(), P(), state_specs),
-        out_specs=out_specs, check_rep=False))
+        out_specs=out_specs, check_vma=False))
     warm = jax.jit(shard_map(
         local_warm, mesh=mesh,
         in_specs=(P(), vec, P(), P(), state_specs),
-        out_specs=out_specs, check_rep=False))
+        out_specs=out_specs, check_vma=False))
     return WarmMLLStepFns(cold=cold, refresh=refresh, warm=warm)
 
 
@@ -954,7 +954,7 @@ def make_mean_cache_solve(mesh: Mesh, geom: DistGeometry, cfg: DistMLLConfig,
     sharded = shard_map(local_fn, mesh=mesh,
                         in_specs=(P(), vec, P()),
                         out_specs=(P(), P()),
-                        check_rep=False)
+                        check_vma=False)
     return jax.jit(sharded)
 
 
@@ -999,7 +999,7 @@ def collective_bench_fns(mesh: Mesh, geom: DistGeometry) -> dict:
 
         fns["ppermute_ring"] = jax.jit(shard_map(
             ring_hop, mesh=mesh, in_specs=(vec,), out_specs=vec,
-            check_rep=False))
+            check_vma=False))
     if geom.col_axes and geom.d_col > 1:
         def scatter(v_loc):
             parts = jnp.tile(v_loc, (geom.d_col, 1))
@@ -1008,5 +1008,5 @@ def collective_bench_fns(mesh: Mesh, geom: DistGeometry) -> dict:
 
         fns["psum_scatter"] = jax.jit(shard_map(
             scatter, mesh=mesh, in_specs=(vec,), out_specs=vec,
-            check_rep=False))
+            check_vma=False))
     return fns

@@ -14,6 +14,7 @@ std 1 as measured on the training split (targets too).
 
 from __future__ import annotations
 
+import zlib
 from typing import NamedTuple
 
 import numpy as np
@@ -83,7 +84,9 @@ def make_regression_dataset(name: str, seed: int = 0, *,
     N, d = DATASET_SPECS[name]
     if max_points is not None:
         N = min(N, max_points)
-    rng = np.random.default_rng(seed + hash(name) % (2 ** 16))
+    # crc32, not hash(): str hashes are salted per process, and every
+    # process (a chip run and its host reference) must see the same data
+    rng = np.random.default_rng(seed + zlib.crc32(name.encode()) % (2 ** 16))
 
     # inputs: correlated gaussian mixture (real UCI inputs are not isotropic)
     ncomp = 3

@@ -62,11 +62,14 @@ _MEMO: dict[str, tuple[int, int]] = {}
 
 
 def default_cache_dir() -> str:
+    """`REPRO_AUTOTUNE_CACHE`, else `<checkout>/.autotune_cache`: a fixed
+    path inside the checkout, so a run reads and writes nothing outside it."""
     env = os.environ.get("REPRO_AUTOTUNE_CACHE")
     if env:
         return env
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "repro-gp", "autotune")
+    from repro.launch.runtime import CHECKOUT
+
+    return os.path.join(CHECKOUT, ".autotune_cache")
 
 
 def shape_bucket(x: int) -> int:
@@ -141,6 +144,13 @@ def _default_measure(key: dict) -> Callable[[int, int], float]:
     return measure
 
 
+def _under_jit_trace() -> bool:
+    """True while a jit trace is active: an array made here is then a
+    Tracer, and so would be a timed launch's result. (Eager autodiff and
+    vmap still compute concrete values, so a sweep there is fine.)"""
+    return isinstance(jnp.zeros(()), jax.core.Tracer)
+
+
 def autotune_tiles(
     components,
     m: int,
@@ -160,8 +170,9 @@ def autotune_tiles(
     measure: (bm, bn) -> seconds; injectable for tests. The default times
     a real fused launch at the bucketed shapes.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    from repro.kernels.ops import resolve_interpret  # lazy: ops -> kmvm
+
+    interpret = resolve_interpret(interpret)
     key = cache_key(components, m, n, d, t,
                     compute_dtype=compute_dtype, interpret=interpret)
     h = key_hash(key)
@@ -181,7 +192,7 @@ def autotune_tiles(
     except (OSError, ValueError, KeyError):
         pass
 
-    if not jax.core.trace_state_clean():
+    if _under_jit_trace():
         # cache miss under an active trace: a timed launch would return
         # tracers. Fall back to the static defaults and do NOT memoize,
         # so a later eager call (prewarm) can still run the sweep.

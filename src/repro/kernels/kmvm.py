@@ -39,8 +39,9 @@ wrapper skips the lane/sublane padding entirely — there is no MXU to
 align for, and padding d 8->128 and t 4->128 was measured as a 16-32x
 flop multiplier on the CPU emulation path.
 
-Validated against `repro.kernels.ref` in interpret mode on CPU (this
-container has no TPU); `repro.kernels.ops` picks interpret automatically.
+Validated against `repro.kernels.ref` in interpret mode on the CPU by the
+tests, and compiled for a described v5e by `tests/test_tpu_compile.py`;
+`repro.kernels.ops.resolve_interpret` decides which mode a launch uses.
 """
 
 from __future__ import annotations
@@ -63,9 +64,19 @@ from repro.core.kernels_math import kernel_from_sqdist
 DEFAULT_BM = 256
 DEFAULT_BN = 512
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+
+def mxu_precision(compute_dtype):
+    """The kernels' dot precision: HIGHEST (6 bf16 passes) for float32
+    operands, DEFAULT (one pass) for 16-bit ones.
+
+    On a v5e the one-pass float32 K_hat @ V missed a float64 reference by
+    ~1e3 times the fp32 tolerance, and Mosaic refuses HIGH. The choice is
+    explicit both ways: Pallas lowers a precision without looking at the
+    operand dtype, so the process-wide default `repro.launch.runtime` sets
+    for XLA's float32 matmuls would otherwise reach the bf16 kernels too.
+    """
+    return (jax.lax.Precision.HIGHEST if jnp.dtype(compute_dtype).itemsize >= 4
+            else jax.lax.Precision.DEFAULT)
 
 
 def scalar_layout(components: tuple) -> int:
@@ -87,7 +98,9 @@ def _kernel_tile(components, compute_dtype, scal_ref, xi_ref, xj_ref):
 
     # MXU: cross term (fp32 accumulation); VPU: norms in fp32
     g = jax.lax.dot_general(
-        xi, xj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        xi, xj, (((1,), (1,)), ((), ())),
+        precision=mxu_precision(compute_dtype),
+        preferred_element_type=jnp.float32)
     xi32 = xi.astype(jnp.float32)
     xj32 = xj.astype(jnp.float32)
     ni = jnp.sum(xi32 * xi32, axis=1, keepdims=True)       # (bm, 1)
@@ -134,6 +147,7 @@ def _kmvm_kernel(components, compute_dtype, scal_ref, xi_ref, xj_ref, v_ref,
     v = v_ref[...].astype(compute_dtype)     # (bn, t)
     out_ref[...] += jax.lax.dot_general(
         k.astype(compute_dtype), v, (((1,), (0,)), ((), ())),
+        precision=mxu_precision(compute_dtype),
         preferred_element_type=jnp.float32)
 
 
@@ -163,6 +177,7 @@ def _kmvm_dots_kernel(components, compute_dtype, scal_ref, xi_ref, xj_ref,
     v = v_ref[...].astype(compute_dtype)     # (bn, t)
     out_ref[...] += jax.lax.dot_general(
         k.astype(compute_dtype), v, (((1,), (0,)), ((), ())),
+        precision=mxu_precision(compute_dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -228,7 +243,7 @@ def kmvm_pallas_dots(
             jax.ShapeDtypeStruct((m, t), jnp.float32),
             jax.ShapeDtypeStruct((m // bm, 8, t), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(scalars, Xi, Xj, V, Vrow, R)
@@ -253,6 +268,7 @@ def _kmvm_acc_kernel(components, compute_dtype, scal_ref, xi_ref, xj_ref,
     v = v_ref[...].astype(compute_dtype)     # (bn, t)
     out_ref[...] += jax.lax.dot_general(
         k.astype(compute_dtype), v, (((1,), (0,)), ((), ())),
+        precision=mxu_precision(compute_dtype),
         preferred_element_type=jnp.float32)
 
 
@@ -305,7 +321,7 @@ def kmvm_pallas_chunk(
         out_specs=pl.BlockSpec((bm, t), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, t), jnp.float32),
         input_output_aliases={4: 0},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(scalars, Xi, Xj, V, acc)
@@ -351,7 +367,7 @@ def kmvm_pallas(
         ],
         out_specs=pl.BlockSpec((bm, t), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, t), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(scalars, Xi, Xj, V)

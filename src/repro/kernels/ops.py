@@ -2,7 +2,8 @@
 
 Handles everything the raw kernel does not: planning a KernelSpec into
 fused passes, lengthscale/weight application, padding of (m, n, d, t) to
-tile multiples, dtype policy, automatic interpret-mode on CPU, and a
+tile multiples, dtype policy, the interpret-mode decision
+(`resolve_interpret`: compiled on TPU, interpreted on CPU), and a
 `block_fn` adapter so `repro.core.partitioned.kmvm` can route its
 per-partition slab MVMs through the Pallas path transparently.
 
@@ -64,8 +65,25 @@ def _pad_axis(A: jax.Array, axis: int, multiple: int) -> jax.Array:
     return jnp.pad(A, widths)
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Whether a Pallas TPU kernel runs in interpret mode.
+
+    The one place the decision is made (the fused kernels, the autotuner
+    and the blocksparse gathered grid all ask here). An explicit bool
+    wins. Otherwise: compiled on a TPU, interpreted on the CPU (tests,
+    local runs), and an error on any other platform — a machine whose TPU
+    failed to initialise must not quietly fall back to the interpreter.
+    """
+    if interpret is not None:
+        return bool(interpret)
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default JAX platform is {platform!r}")
 
 
 class _PallasPass(NamedTuple):
@@ -229,8 +247,7 @@ def kmvm_block(
     accumulates in fp32; None/"float32" is the exact path. All elementwise
     kernel math stays fp32 regardless.
     """
-    if interpret is None:
-        interpret = _auto_interpret()
+    interpret = resolve_interpret(interpret)
     cdt = jnp.dtype(compute_dtype if compute_dtype is not None else jnp.float32)
     squeeze = V.ndim == 1
     if squeeze:
@@ -298,8 +315,7 @@ def kmvm_fused_matmat(
     Requires the spec to plan to a single fused pass
     (`fused_pass_or_none`); raises ValueError otherwise — callers gate.
     """
-    if interpret is None:
-        interpret = _auto_interpret()
+    interpret = resolve_interpret(interpret)
     cdt = jnp.dtype(compute_dtype if compute_dtype is not None else jnp.float32)
     ppass = fused_pass_or_none(kernel, params)
     if ppass is None:

@@ -204,7 +204,7 @@ def run_gp_cell(kind: str, mesh, pcg_method="standard", mode=None,
     if overlap:
         GP = GP._replace(overlap=True)
     if backend == "pallas":
-        # Off-TPU the Pallas kernel auto-selects interpret mode, so the
+        # Off-TPU the Pallas kernel runs in interpret mode, so the
         # compiled artifact would be the interpreter's emulation HLO —
         # cost_analysis would report the emulation's flops/bytes (every
         # kernel tile materialized), describing neither the fused kernel's
@@ -213,7 +213,7 @@ def run_gp_cell(kind: str, mesh, pcg_method="standard", mode=None,
         raise ValueError(
             "--gp-backend pallas is only meaningful on a TPU host: the "
             "CPU dry-run would measure the Pallas interpreter, not the "
-            "fused kernel (see repro.kernels.ops._auto_interpret)")
+            "fused kernel (see repro.kernels.ops.resolve_interpret)")
     if backend is not None:
         GP = GP._replace(backend=backend)
     if compute_dtype is not None:

@@ -14,20 +14,31 @@ Axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(axes) -> tuple:
+    # jax.make_mesh defaults to Explicit axes; this code base shards with
+    # device_put + shard_map and lets XLA propagate the rest (Auto), so
+    # arrays leaving a mesh step (trained params) mix with unsharded ones
+    return (AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, _auto(axes))
 
 
 def make_host_mesh(data: int | None = None, model: int = 1):
-    """Small mesh over whatever devices exist (tests / local runs)."""
-    n = len(jax.devices())
+    """Small mesh over the first data * model local devices (all of them
+    when `data` is None) — so a 1x1 mesh on a four-chip host holds one."""
+    devices = jax.devices()
     if data is None:
-        data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+        data = len(devices) // model
+    axes = ("data", "model")
+    return jax.make_mesh((data, model), axes, _auto(axes),
+                         devices=devices[:data * model])
 
 
 def mesh_axis_sizes(mesh) -> dict[str, int]:

@@ -29,11 +29,8 @@ import argparse
 import json
 
 from repro.obs.health import load_health, summarize_health
-from repro.obs.measure import (
-    DEFAULT_HBM_GBPS,
-    format_model_comparison,
-    phase_model_comparison,
-)
+from repro.obs.measure import format_model_comparison, phase_model_comparison
+from repro.obs.peaks import V5E
 from repro.obs.report import (
     assign_self_times,
     format_report,
@@ -58,9 +55,11 @@ def main(argv=None):
                          "(needs a trace from a traced fit: the engine's "
                          "phased dispatch stamps measured_ms + modeled "
                          "bytes on each phase span)")
-    ap.add_argument("--hbm-gbps", type=float, default=DEFAULT_HBM_GBPS,
-                    help="reference HBM bandwidth for modeled-bytes -> "
-                         "modeled-ms conversion (default %(default)s)")
+    ap.add_argument("--device-kind", default=V5E,
+                    help="device kind (jax device_kind) whose published HBM "
+                         "bandwidth converts modeled bytes to modeled ms; "
+                         "must be listed in repro.obs.peaks "
+                         "(default %(default)r)")
     ap.add_argument("--health", default=None,
                     help="solver health-event JSONL (REPRO_OBS_HEALTH) to "
                          "summarize alongside the trace")
@@ -83,7 +82,7 @@ def main(argv=None):
         }
         if args.compare_model:
             payload["model_comparison"] = phase_model_comparison(
-                events, hbm_gbps=args.hbm_gbps)
+                events, device_kind=args.device_kind)
         if args.health:
             payload["health"] = summarize_health(load_health(args.health))
         print(json.dumps(payload, indent=1))
@@ -91,9 +90,9 @@ def main(argv=None):
 
     print(format_report(args.trace, root=args.root))
     if args.compare_model:
-        rows = phase_model_comparison(events, hbm_gbps=args.hbm_gbps)
+        rows = phase_model_comparison(events, device_kind=args.device_kind)
         print("\n## Measured vs modeled\n")
-        print(format_model_comparison(rows, hbm_gbps=args.hbm_gbps))
+        print(format_model_comparison(rows, device_kind=args.device_kind))
     if args.health:
         summary = summarize_health(load_health(args.health))
         print("\n## Solver health\n")

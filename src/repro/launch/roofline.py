@@ -16,7 +16,7 @@ Caveats (documented, consistent across cells, so deltas are meaningful):
   * cost_analysis "bytes accessed" counts every HLO op's operands+outputs —
     an upper bound on HBM traffic that ignores fusion-internal reuse. XLA's
     CPU backend applies the same counting rules to every cell.
-  * link bandwidth is per the assignment: one ~50 GB/s ICI link; real v5e
+  * link bandwidth is one 50 GB/s ICI link (`repro.obs.peaks`); real v5e
     tori overlap multiple links/directions, so collective terms are
     conservative.
 """
@@ -26,27 +26,26 @@ from __future__ import annotations
 import re
 from typing import NamedTuple
 
-# TPU v5e per-chip constants (assignment-specified)
-PEAK_FLOPS = 197e12        # bf16 FLOP/s
-PEAK_FLOPS_FP32 = PEAK_FLOPS / 2   # MXU fp32 operands run at half rate
-HBM_BW = 819e9             # bytes/s
-LINK_BW = 50e9             # bytes/s per ICI link
+from repro.obs.peaks import V5E, peaks_for
+
+# the dry-run prices a TPU v5e chip, at its published peaks
 
 
 def peak_flops_for(compute_dtype: str | None) -> float:
-    """MXU peak for the cell's matmul operand dtype. The KernelOperator
-    mixed-precision path ("bfloat16") earns the full bf16 peak; fp32
-    operands (the exact GP default) are charged at half — this is exactly
-    the 2x the bf16-compute operator option buys on compute-bound cells.
+    """MXU peak for the cell's matmul operand dtype: the bf16 peak for the
+    KernelOperator mixed-precision path ("bfloat16"), the HIGHEST-precision
+    float32 peak (`DevicePeaks.fp32_flops`, 6 bf16 passes) for fp32
+    operands, the exact GP default.
 
     Known coarseness: one dtype is charged for the WHOLE cell. A bf16
     gp_train cell's MLL backward is pinned to fp32 (see mll._mll_bwd), so
     its ~10-12% backward flop share (EXPERIMENTS.md §Roofline) is
-    over-credited 2x — a <= ~6% optimistic skew on t_compute, consistent
-    across cells."""
+    over-credited 6x: t_compute reads up to ~40% low (0.88 + 0.12 bf16
+    units charged against 0.88 + 0.72 taken), consistent across cells."""
+    peaks = peaks_for(V5E)
     if compute_dtype in (None, "fp32", "float32", "f32"):
-        return PEAK_FLOPS_FP32
-    return PEAK_FLOPS
+        return peaks.fp32_flops
+    return peaks.bf16_flops
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -156,10 +155,11 @@ def analyze(cost: dict, coll: dict, model_flops_global: float,
     byts = float(cost.get("bytes accessed", 0.0) or 0.0)
     cb = float(coll["total"])
     wb = float(coll.get("wire", cb))
+    peaks = peaks_for(V5E)
     t_c = flops / peak_flops_for(compute_dtype)
-    t_m = byts / HBM_BW
-    t_x = cb / LINK_BW
-    t_w = wb / LINK_BW
+    t_m = byts / peaks.hbm_bytes_per_s
+    t_x = cb / peaks.ici_link_bytes_per_s
+    t_w = wb / peaks.ici_link_bytes_per_s
     terms = {"compute": t_c, "memory": t_m, "collective": t_w}
     bott = max(terms, key=terms.get)
     mf = model_flops_global / max(n_devices, 1)

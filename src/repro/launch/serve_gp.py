@@ -31,6 +31,7 @@ from repro import obs
 from repro.core import ExactGP, ExactGPConfig
 from repro.core.predcache import predict_mean, predict_var_cached
 from repro.data import make_regression_dataset
+from repro.launch.runtime import setup_runtime
 from repro.serve import (
     BatcherConfig, FleetConfig, MicroBatcher, PredictionEngine,
     SchedulerConfig, ServeFleet, fit_posterior, load_artifact, save_artifact,
@@ -76,9 +77,10 @@ def _fit_or_load(args):
     return art
 
 
-def _verify(engine: PredictionEngine, Xq: jax.Array) -> float:
-    """Max rel. error of the chunked engine vs the unchunked predcache
-    reference on the SAME operator (the acceptance oracle)."""
+def verify_engine(engine: PredictionEngine,
+                  Xq: jax.Array) -> tuple[float, float]:
+    """(mean, var) max rel. error of the chunked engine vs the unchunked
+    predcache reference on the SAME operator (the acceptance oracle)."""
     mean, var = engine.predict(Xq)
     cache = engine.artifact.cache()
     ref_m = predict_mean(engine.op, Xq, cache)
@@ -86,10 +88,8 @@ def _verify(engine: PredictionEngine, Xq: jax.Array) -> float:
                                include_noise=engine.include_noise)
     # scale-relative: max |delta| over the reference scale (element-wise
     # relative error is meaningless where the whitened mean crosses zero)
-    rel = max(
-        float(jnp.max(jnp.abs(mean - ref_m)) / jnp.max(jnp.abs(ref_m))),
-        float(jnp.max(jnp.abs(var - ref_v)) / jnp.max(jnp.abs(ref_v))))
-    return rel
+    return (float(jnp.max(jnp.abs(mean - ref_m)) / jnp.max(jnp.abs(ref_m))),
+            float(jnp.max(jnp.abs(var - ref_v)) / jnp.max(jnp.abs(ref_v))))
 
 
 def main():
@@ -126,6 +126,7 @@ def main():
                          "breaches count into serve.slo_breach.<model> and "
                          "the per-model burn rate is printed")
     args = ap.parse_args()
+    setup_runtime()
 
     art = _fit_or_load(args)
     engine = PredictionEngine(
@@ -139,7 +140,7 @@ def main():
     pool = np.asarray(art.X)[rng.integers(0, art.n, size=2048)]
     pool = pool + 0.1 * rng.standard_normal(pool.shape).astype(pool.dtype)
 
-    rel = _verify(engine, jnp.asarray(pool[:512]))
+    rel = max(verify_engine(engine, jnp.asarray(pool[:512])))
     exact_path = engine.config.compute_dtype is None
     print(f"[serve-gp] engine vs unchunked reference: max rel err {rel:.2e} "
           f"({'exact fp32 path, bound 1e-5' if exact_path else 'bf16 path'})")

@@ -131,7 +131,7 @@ def make_gp_train_step(gp_cfg, mesh: Mesh, *, lr: float = 0.1,
     """(X, y, params, opt, key) -> (loss, params, opt): one BBMM MLL Adam step."""
     from repro.core.distributed import (
         DistMLLConfig, make_dist_mll, make_geometry)
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     geom = make_geometry(mesh, gp_cfg.n, gp_cfg.d, mode=gp_cfg.mode,
                          row_block=gp_cfg.row_block,
@@ -157,7 +157,7 @@ def make_gp_train_step(gp_cfg, mesh: Mesh, *, lr: float = 0.1,
         local_fn, mesh=mesh,
         in_specs=(P(), vec, P(), P(), P(), P(), P()),
         out_specs=(P(), P(), P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
     return sharded, geom
 
 

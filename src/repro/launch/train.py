@@ -16,8 +16,11 @@ from __future__ import annotations
 
 import argparse
 import os
+from typing import NamedTuple
 
 import jax
+
+from repro.launch.runtime import setup_runtime
 
 
 def _maybe_init_distributed():
@@ -74,6 +77,7 @@ def main():
                          "REPRO_OBS_TRACE")
     args = ap.parse_args()
     _maybe_init_distributed()
+    setup_runtime()
 
     if args.obs_trace:
         from repro import obs
@@ -152,68 +156,86 @@ def prepare_gp_data(mesh, X_host, y_host, *, backend, gp_mode, kernel,
     return geom, X, y, None
 
 
-def _train_gp(args):
+class GPTrainRun(NamedTuple):
+    """What `train_gp` leaves behind: the trained hyperparameters, the
+    padded device-resident data, and one record per optimizer step."""
+
+    params: object
+    X: jax.Array          # (geom.n_padded, d); rows [geom.n:] are pad
+    y: jax.Array          # (geom.n_padded,)
+    geom: object          # DistGeometry
+    cfg: object           # DistMLLConfig
+    plan: object          # SparsePlan or None
+    history: list         # per step: loss, grads (host), mode, cg_iters,
+                          # seconds (wall, ended by block_until_ready)
+
+
+def train_gp(mesh, X_host, y_host, workload, *, steps: int,
+             refresh_every: int = 5,
+             drift_threshold: float = 0.1) -> GPTrainRun:
+    """Adam on the exact-GP MLL with the distributed warm-start engine.
+
+    workload: a `configs.gp_exact_1m.GPWorkloadConfig` — kernel, backend,
+    compute dtype, mesh mode, overlap and the solver widths (precond rank,
+    probes, CG iterations). Every row of (X_host, y_host) trains.
+    """
     import jax.numpy as jnp
+    import numpy as np
 
     from repro.core import KERNEL_KINDS, init_params_for, parse_kernel, spec_expr
     from repro.core.distributed import (
         DistMLLConfig, replicate, shard_vector,
     )
-    from repro.data import make_regression_dataset
-    from repro.launch.mesh import make_host_mesh
     from repro.optim import adam_init, adam_update
     from repro.train.solver_state import DistWarmStartEngine, WarmStartConfig
 
-    mesh = make_host_mesh(data=args.data, model=args.model)
-    s = make_regression_dataset("houseelectric", max_points=args.gp_n * 3)
-    gp_mode = args.gp_mode
-    gp_dtype = None if args.gp_dtype == "float32" else args.gp_dtype
+    gp_dtype = workload.compute_dtype
     # legacy stationary kinds train the flat GPParams (the paper's setup);
     # any other expression parses to a KernelSpec + per-node KernelParams
     # (one dispatch rule for model/launcher/tests: init_params_for)
-    kernel = args.gp_kernel if args.gp_kernel in KERNEL_KINDS \
-        else parse_kernel(args.gp_kernel)
+    kernel = workload.kernel if workload.kernel in KERNEL_KINDS \
+        else parse_kernel(workload.kernel)
     params = init_params_for(kernel, noise=0.3, dtype=jnp.float32)
     kernel_desc = kernel if isinstance(kernel, str) else spec_expr(kernel)
 
     geom, X, y, plan = prepare_gp_data(
-        mesh, s.X_train, s.y_train, backend=args.gp_backend,
-        gp_mode=gp_mode, kernel=kernel, params=params,
-        margin=args.gp_drift_threshold, overlap=args.gp_overlap)
+        mesh, X_host, y_host, backend=workload.backend,
+        gp_mode=workload.mode, kernel=kernel, params=params,
+        margin=drift_threshold, overlap=workload.overlap,
+        row_block=workload.row_block)
     n = geom.n
-    assert n == s.X_train.shape[0], "no training point may be dropped"
+    assert n == X_host.shape[0], "no training point may be dropped"
     if plan is not None:
         print(f"[train-gp] sparsity plan: {plan}")
     if geom.has_pad:
         print(f"[train-gp] padded layout: {geom.pad_rows} masked rows "
               f"({n} -> {geom.n_padded})")
-    cfg = DistMLLConfig(kernel=kernel, precond_rank=100, num_probes=8,
-                        max_cg_iters=20, cg_tol=1.0, backend=args.gp_backend,
-                        compute_dtype=gp_dtype, plan=plan)
-    warm = WarmStartConfig(enabled=args.gp_refresh_every > 0,
-                           refresh_every=max(args.gp_refresh_every, 1),
-                           drift_threshold=args.gp_drift_threshold)
+    cfg = DistMLLConfig(kernel=kernel, precond_rank=workload.precond_rank,
+                        num_probes=workload.num_probes,
+                        max_cg_iters=workload.train_cg_iters, cg_tol=1.0,
+                        backend=workload.backend, compute_dtype=gp_dtype,
+                        plan=plan)
+    warm = WarmStartConfig(enabled=refresh_every > 0,
+                           refresh_every=max(refresh_every, 1),
+                           drift_threshold=drift_threshold)
     engine = DistWarmStartEngine(mesh, geom, cfg, warm)
     state = adam_init(params)
-    telemetry_done: list = []  # closed-out engines' telemetry (replans)
+    history: list = []
     Xr, ys = replicate(mesh, X), shard_vector(mesh, geom, y)
-    print(f"[train-gp] n={n} kernel={kernel_desc} mode={gp_mode} "
-          f"backend={args.gp_backend} "
-          f"dtype={args.gp_dtype} refresh_every={args.gp_refresh_every} "
+    print(f"[train-gp] n={n} kernel={kernel_desc} mode={workload.mode} "
+          f"backend={workload.backend} "
+          f"dtype={gp_dtype or 'float32'} refresh_every={refresh_every} "
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}")
-    for step_i in range(args.steps):
+    for step_i in range(steps):
         if plan is not None:
             from repro.sparse import build_plan, needs_replan
 
-            replan, _drift = needs_replan(plan, params,
-                                          args.gp_drift_threshold,
+            replan, _drift = needs_replan(plan, params, drift_threshold,
                                           kernel=kernel)
             if replan:
                 plan = build_plan(kernel, X, params, tile=plan.tile,
-                                  margin=args.gp_drift_threshold,
-                                  assume_sorted=True)
+                                  margin=drift_threshold, assume_sorted=True)
                 cfg = cfg._replace(plan=plan)
-                telemetry_done.extend(engine.telemetry)
                 engine = DistWarmStartEngine(mesh, geom, cfg, warm)
                 print(f"[train-gp] step {step_i}: replanned sparsity "
                       f"(drift={_drift:.3f}, fill={plan.fill:.3f})")
@@ -221,14 +243,37 @@ def _train_gp(args):
                                        jax.random.PRNGKey(step_i))
         params, state = adam_update(params, grads, state, 0.1)
         t = engine.telemetry[-1]
+        history.append({"step": step_i, "loss": float(loss),
+                        "grads": jax.tree.map(np.asarray,
+                                              jax.device_get(grads)),
+                        "mode": t["mode"], "cg_iters": t["cg_iters"],
+                        "refreshed": t["refreshed"],
+                        "seconds": t["seconds"]})
         print(f"[train-gp] step {step_i}: nll/n={float(loss):.4f} "
               f"solve={t['mode']} cg_iters={t['cg_iters']} "
               f"drift={t['drift']:.3f} dt={t['seconds']:.2f}s")
-    telemetry_done.extend(engine.telemetry)
-    total = sum(t["cg_iters"] for t in telemetry_done)
-    refreshes = sum(t["refreshed"] for t in telemetry_done)
+    total = sum(h["cg_iters"] for h in history)
+    refreshes = sum(h["refreshed"] for h in history)
     print(f"[train-gp] solver telemetry: total_cg_iters={total} "
-          f"precond_refreshes={refreshes} steps={args.steps}")
+          f"precond_refreshes={refreshes} steps={steps}")
+    return GPTrainRun(params=params, X=X, y=y, geom=geom, cfg=cfg,
+                      plan=plan, history=history)
+
+
+def _train_gp(args):
+    from repro.configs.gp_exact_1m import CONFIG
+    from repro.data import make_regression_dataset
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh(data=args.data, model=args.model)
+    s = make_regression_dataset("houseelectric", max_points=args.gp_n * 3)
+    workload = CONFIG._replace(
+        kernel=args.gp_kernel, mode=args.gp_mode, backend=args.gp_backend,
+        compute_dtype=None if args.gp_dtype == "float32" else args.gp_dtype,
+        overlap=args.gp_overlap)
+    run = train_gp(mesh, s.X_train, s.y_train, workload, steps=args.steps,
+                   refresh_every=args.gp_refresh_every,
+                   drift_threshold=args.gp_drift_threshold)
 
     if args.save_artifact:
         # mesh-trained hyperparameters -> a servable single-host artifact
@@ -237,20 +282,21 @@ def _train_gp(args):
         from repro.core import OperatorConfig, make_operator
         from repro.serve.artifact import fit_posterior, save_artifact
 
-        X_true, y_true = X[:n], y[:n]
+        n, cfg, params = run.geom.n, run.cfg, run.params
+        X_true, y_true = run.X[:n], run.y[:n]
         assert X_true.shape[0] == s.X_train.shape[0], \
             "artifact must cover every original training row"
         art_plan = None
-        if plan is not None:
+        if run.plan is not None:
             from repro.sparse import build_plan
 
             art_plan = build_plan(cfg.kernel, X_true, params,
-                                  tile=plan.tile,
+                                  tile=run.plan.tile,
                                   margin=args.gp_drift_threshold,
                                   assume_sorted=True)
         op = make_operator(
             OperatorConfig(kernel=cfg.kernel, backend=args.gp_backend,
-                           compute_dtype=gp_dtype, plan=art_plan),
+                           compute_dtype=cfg.compute_dtype, plan=art_plan),
             X_true, params)
         art = fit_posterior(op, y_true, jax.random.PRNGKey(args.steps),
                             precond_rank=cfg.precond_rank)

@@ -18,8 +18,8 @@ Three pieces:
   aggregates those spans per (backend, phase) into a measured-vs-modeled
   table — `launch/obs_report --compare-model`.
 * **Modeled-ms conversion**: modeled bytes become modeled milliseconds
-  through a reference HBM bandwidth (`--hbm-gbps`; default DEFAULT_HBM_GBPS
-  — set it to the target part's spec sheet). The measured/modeled RATIO is
+  through the HBM bandwidth of a device kind (`--device-kind`, looked up
+  in `repro.obs.peaks`; an unlisted kind is an error). The measured/modeled RATIO is
   the honest quantity: ~1 means the byte model explains the time; >> 1
   means launch overhead / host sync dominates (expected on CPU emulation);
   << 1 means the model overcharges (e.g. cached slabs).
@@ -39,10 +39,7 @@ import numpy as np
 
 from . import costmodel
 from . import metrics as _metrics
-
-# reference bandwidth for modeled-bytes -> modeled-ms conversion; roughly
-# a single HBM2 stack — override per target part via --hbm-gbps
-DEFAULT_HBM_GBPS = 100.0
+from .peaks import V5E, peaks_for
 
 # the four phase-span names the training engine emits (and the order the
 # comparison table lists them in)
@@ -50,7 +47,7 @@ PHASE_SPANS = ("precond_build", "cg_solve", "slq_logdet", "eq2_backward")
 
 
 def phase_model_comparison(spans: list[dict], *,
-                           hbm_gbps: float = DEFAULT_HBM_GBPS) -> list[dict]:
+                           device_kind: str = V5E) -> list[dict]:
     """Aggregate phase spans into measured-vs-modeled rows.
 
     spans: trace events (`obs.report.load_trace`). Only spans carrying BOTH
@@ -81,11 +78,12 @@ def phase_model_comparison(spans: list[dict], *,
             pi = len(PHASE_SPANS)
         return (backend, pi, phase)
 
+    hbm_bytes_per_s = peaks_for(device_kind).hbm_bytes_per_s
     rows = []
     for key in sorted(groups, key=order):
         backend, phase = key
         g = groups[key]
-        modeled_ms = g["modeled_hbm_bytes"] / (hbm_gbps * 1e9) * 1e3
+        modeled_ms = g["modeled_hbm_bytes"] / hbm_bytes_per_s * 1e3
         rows.append({
             "backend": backend,
             "phase": phase,
@@ -101,10 +99,11 @@ def phase_model_comparison(spans: list[dict], *,
 
 
 def format_model_comparison(rows: list[dict], *,
-                            hbm_gbps: float = DEFAULT_HBM_GBPS) -> str:
+                            device_kind: str = V5E) -> str:
     """Render the measured-vs-modeled table (obs_report --compare-model)."""
-    lines = [f"measured vs modeled (reference HBM bandwidth "
-             f"{hbm_gbps:g} GB/s)",
+    gbps = peaks_for(device_kind).hbm_bytes_per_s / 1e9
+    lines = [f"measured vs modeled (HBM bandwidth of {device_kind}: "
+             f"{gbps:g} GB/s)",
              f"{'backend':<12} {'phase':<14} {'steps':>5} "
              f"{'measured_ms':>12} {'modeled_ms':>11} {'modeled_GB':>11} "
              f"{'ratio':>8}"]

@@ -12,9 +12,6 @@ and serve `predict(Xstar)` with
   * streaming memory — one chunk's (chunk, r) cross-products are live at a
     time; the (n*, n) kernel block is never materialized, so 10^5-point test
     batches stream against million-point train sets;
-  * donated query buffers — each chunk's input buffer is donated to the
-    compiled call on accelerator backends (no-op on CPU, where XLA cannot
-    alias donations);
   * optional bf16 cross-MVMs — `compute_dtype="bfloat16"` re-binds the
     operator with the mixed fast path (bf16 operands, fp32 MXU accumulation;
     see EXPERIMENTS.md §Mixed precision). Cache state stays fp32 regardless.
@@ -94,14 +91,23 @@ class PredictionEngine:
         self.rows_served = 0
         self._counter_lock = threading.Lock()
 
-        def _chunk(Xc: jax.Array):
-            mean = predict_mean(self.op, Xc, self._cache)
-            var = predict_var_cached(self.op, Xc, self._cache,
+        # the artifact's arrays enter the compiled chunk program as
+        # arguments, not closure constants: as constants XLA folds the
+        # hyperparameter transforms on the host (the served values then
+        # drift ulps away from the same math run on the device) and
+        # compiles the whole training set and Lanczos cache into the program
+        op_config = self.op.config  # carries the plan a blocksparse op built
+
+        def _chunk(X, params, cache, Xc):
+            op = make_operator(op_config, X, params)
+            mean = predict_mean(op, Xc, cache)
+            var = predict_var_cached(op, Xc, cache,
                                      include_noise=include_noise)
             return mean, var
 
-        donate = () if jax.default_backend() == "cpu" else (0,)
-        self._predict_chunk = jax.jit(_chunk, donate_argnums=donate)
+        chunk_fn = jax.jit(_chunk)
+        self._predict_chunk = lambda Xc: chunk_fn(
+            self.op.X, self.op.params, self._cache, Xc)
 
     @classmethod
     def from_dir(cls, directory: str, **kwargs) -> "PredictionEngine":

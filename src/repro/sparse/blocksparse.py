@@ -252,23 +252,24 @@ class BlockSparseOperator(KernelOperator):
     # -- the pruned MVM -----------------------------------------------------
 
     def _use_pallas(self) -> bool:
-        if self.config.interpret is True:
-            return True
+        """The gathered grid runs compiled on a TPU and, off it, only in
+        interpret mode when the config forces it (the test hook);
+        interpret=False off-TPU selects the masked-partitioned path."""
         if self.config.interpret is None:
             return jax.default_backend() == "tpu"
-        return False  # interpret=False off-TPU: masked-partitioned path
+        return self.config.interpret
 
     def _sorted_kmvm(self, Xs: jax.Array, Vs: jax.Array) -> jax.Array:
+        from repro.kernels.ops import resolve_interpret
+
         cdt = _compute_dtype_of(self.config, self.dtype)
         if self._use_pallas():
             ppass = _fused_pass_or_none(self.config.kernel, self.params)
             if ppass is not None:
-                interpret = (self.config.interpret
-                             if self.config.interpret is not None
-                             else jax.default_backend() != "tpu")
                 out = pallas_sorted_kmvm(
                     ppass, Xs, Vs, self.plan,
-                    interpret=interpret, compute_dtype=cdt)
+                    interpret=resolve_interpret(self.config.interpret),
+                    compute_dtype=cdt)
                 return out.astype(Vs.dtype)
         return masked_kmvm(self.config.kernel, Xs, Vs, self.params,
                            self.plan, compute_dtype=cdt)

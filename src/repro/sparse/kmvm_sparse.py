@@ -34,11 +34,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.kernels_math import kernel_from_sqdist
+from repro.kernels.kmvm import mxu_precision
 
 _LANE = 128
-
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 
 def _bs_kernel(components, compute_dtype, rows_ref, cols_ref, first_ref,
@@ -60,7 +58,9 @@ def _bs_kernel(components, compute_dtype, rows_ref, cols_ref, first_ref,
     v = v_ref[...].astype(compute_dtype)     # (tile, t)
 
     g = jax.lax.dot_general(
-        xi, xj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        xi, xj, (((1,), (1,)), ((), ())),
+        precision=mxu_precision(compute_dtype),
+        preferred_element_type=jnp.float32)
     xi32 = xi.astype(jnp.float32)
     xj32 = xj.astype(jnp.float32)
     ni = jnp.sum(xi32 * xi32, axis=1, keepdims=True)
@@ -88,6 +88,7 @@ def _bs_kernel(components, compute_dtype, rows_ref, cols_ref, first_ref,
 
     out_ref[...] += jax.lax.dot_general(
         k.astype(compute_dtype), v, (((1,), (0,)), ((), ())),
+        precision=mxu_precision(compute_dtype),
         preferred_element_type=jnp.float32)
 
 
@@ -135,6 +136,6 @@ def kmvm_blocksparse_pallas(
         functools.partial(_bs_kernel, components, jnp.dtype(compute_dtype)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_pad, t), jnp.float32),
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(pair_rows, pair_cols, pair_first, scalars, Xs, Xs, V)

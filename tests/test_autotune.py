@@ -207,3 +207,14 @@ def test_tiles_for_spec_and_prewarm_route_through_cache(tmp_path, rng):
                           compute_dtype="float32", interpret=True,
                           cache_dir=cdir)
     assert got2 == (256, 256)
+
+
+def test_default_cache_dir_is_in_the_checkout(monkeypatch, tmp_path):
+    from repro.kernels.autotune import default_cache_dir
+    from repro.launch.runtime import CHECKOUT
+
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
+    assert default_cache_dir() == os.path.join(CHECKOUT, ".autotune_cache")
+    assert os.path.isdir(os.path.join(CHECKOUT, "src", "repro"))
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path))
+    assert default_cache_dir() == str(tmp_path)

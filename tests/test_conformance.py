@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import (
     MLLConfig,
@@ -123,7 +123,7 @@ def test_matvec_and_diag_conformance(kernel, dtype, shape):
     f = jax.jit(shard_map(
         lambda Xr, Vl: dist_kmvm(geom, kernel, Xr, Vl, params),
         mesh=mesh, in_specs=(P(), geom.vector_pspec()),
-        out_specs=geom.vector_pspec(), check_rep=False))
+        out_specs=geom.vector_pspec(), check_vma=False))
     out = f(replicate(mesh, X), shard_vector(mesh, geom, V))
     np.testing.assert_allclose(np.asarray(out), ref_mv, rtol=tol, atol=tol,
                                err_msg="sharded")
@@ -309,7 +309,7 @@ def test_blocksparse_2d_mesh_conformance(n, overlap):
         lambda Xr, Vl: dist_blocksparse_kmvm(geom, kernel, Xr, Vl, params,
                                              plan),
         mesh=mesh, in_specs=(P(), geom.vector_pspec()),
-        out_specs=geom.vector_pspec(), check_rep=False))
+        out_specs=geom.vector_pspec(), check_vma=False))
     out = np.asarray(f(replicate(mesh, Xp), shard_vector(mesh, geom, Vp)))
     ref = np.asarray(dense_khat(kernel, Xs, params) @ V)
     np.testing.assert_allclose(out[:n], ref, rtol=1e-10, atol=1e-10)

@@ -103,3 +103,23 @@ def test_warmup_cosine_shape():
     assert float(s(10)) == pytest.approx(1.0)
     assert float(s(100)) == pytest.approx(0.1, abs=1e-6)
     assert float(s(55)) < float(s(20))
+
+
+def test_dataset_is_the_same_in_every_process():
+    """Seeding must not depend on Python's per-process str-hash salt: a
+    chip run and its host reference have to see the same split."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import hashlib; from repro.data import make_regression_dataset;"
+            "s = make_regression_dataset('bike', max_points=900);"
+            "print(hashlib.sha1(b''.join(a.tobytes() for a in s)).hexdigest())")
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    digests = set()
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, PYTHONPATH=src)
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, check=True)
+        digests.add(out.stdout.strip().splitlines()[-1])
+    assert len(digests) == 1, digests

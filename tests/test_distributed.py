@@ -15,7 +15,7 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import dense_khat, dense_mll, init_params, pivoted_cholesky
 from repro.core.distributed import (
@@ -39,7 +39,7 @@ for mode in ("1d", "2d"):
     f = jax.jit(shard_map(
         lambda Xr, V_loc: dist_kmvm(geom, "matern32", Xr, V_loc, params),
         mesh=mesh, in_specs=(P(), geom.vector_pspec()),
-        out_specs=geom.vector_pspec(), check_rep=False))
+        out_specs=geom.vector_pspec(), check_vma=False))
     out = f(replicate(mesh, X), shard_vector(mesh, geom, V))
     assert float(jnp.max(jnp.abs(out - Khat @ V))) < 1e-10, mode
 
@@ -47,7 +47,7 @@ for mode in ("1d", "2d"):
     g = jax.jit(shard_map(
         lambda Xr: make_dist_preconditioner(geom, "matern32", Xr, params, 40).L_local,
         mesh=mesh, in_specs=(P(),), out_specs=geom.vector_pspec(),
-        check_rep=False))
+        check_vma=False))
     L_dist = g(replicate(mesh, X))
     L_ref = pivoted_cholesky("matern32", X, params, 40)
     assert float(jnp.max(jnp.abs(L_dist - L_ref))) < 1e-9, mode

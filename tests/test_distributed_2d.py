@@ -60,7 +60,7 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import init_params
 from repro.core.distributed import (
@@ -82,7 +82,7 @@ for mode in ("1d", "2d"):
     f = jax.jit(shard_map(
         lambda Xr, Vl: dist_kmvm(geom, "matern32", Xr, Vl, params),
         mesh=mesh, in_specs=(P(), geom.vector_pspec()),
-        out_specs=geom.vector_pspec(), check_rep=False))
+        out_specs=geom.vector_pspec(), check_vma=False))
     out = np.asarray(f(replicate(mesh, X), shard_vector(mesh, geom, V)))
     cfg = DistMLLConfig(kernel="matern32", precond_rank=40, num_probes=8,
                         max_cg_iters=30, cg_tol=1e-8)
@@ -106,7 +106,7 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import init_params, parse_kernel
 from repro.core.kernels_math import init_kernel_params
@@ -125,7 +125,7 @@ def run_dense(geom, X, V, params, overlap):
         lambda Xr, Vl: dist_kmvm(geom, "matern32", Xr, Vl, params,
                                  overlap=overlap),
         mesh=mesh, in_specs=(P(), geom.vector_pspec()),
-        out_specs=geom.vector_pspec(), check_rep=False))
+        out_specs=geom.vector_pspec(), check_vma=False))
     return np.asarray(f(replicate(mesh, X), shard_vector(mesh, geom, V)))
 
 for n in (256, 250):
@@ -158,7 +158,7 @@ for n in (256, 250):
             lambda Xr, Vl: dist_blocksparse_kmvm(geom, spec, Xr, Vl, kp,
                                                  plan, overlap=overlap),
             mesh=mesh, in_specs=(P(), geom.vector_pspec()),
-            out_specs=geom.vector_pspec(), check_rep=False))
+            out_specs=geom.vector_pspec(), check_vma=False))
         outs.append(np.asarray(f(replicate(mesh, Xp),
                                  shard_vector(mesh, geom, Vp))))
     assert (outs[0] == outs[1]).all(), f"blocksparse n={n}: not bitwise"

@@ -141,3 +141,20 @@ def test_kmvm_pallas_chunk_matches_single_launch():
             components, Xi, Xj[s * nc:(s + 1) * nc], V[s * nc:(s + 1) * nc],
             scalars, acc, bm=32, bn=32, interpret=True)
     np.testing.assert_array_equal(np.asarray(acc), np.asarray(full))
+
+
+@pytest.mark.parametrize("platform,want", [("tpu", False), ("cpu", True),
+                                           ("gpu", None)])
+def test_interpret_mode_follows_the_platform(monkeypatch, platform, want):
+    """Compiled on a TPU, interpreted on the CPU, an error anywhere else —
+    never a silent fall-back to the interpreter; an explicit bool wins."""
+    from repro.kernels.ops import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if want is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            resolve_interpret()
+    else:
+        assert resolve_interpret() is want
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False

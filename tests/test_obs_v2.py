@@ -481,18 +481,23 @@ def test_phase_model_comparison_aggregates_spans():
     span = _ev("cg_solve", 0.0, 5000.0, measured_ms=5.0,
                modeled_hbm_bytes=1e9, backend="dense", modeled_launches=3)
     other = _ev("misc", 0.0, 10.0)  # no modeled args: ignored
-    rows = phase_model_comparison([span, span, other], hbm_gbps=100.0)
+    rows = phase_model_comparison([span, span, other],
+                                  device_kind="TPU v5 lite")
     assert len(rows) == 1
     r = rows[0]
     assert r["backend"] == "dense" and r["phase"] == "cg_solve"
     assert r["steps"] == 2
     assert r["measured_ms"] == pytest.approx(10.0)
-    assert r["modeled_ms"] == pytest.approx(20.0)  # 2 GB at 100 GB/s
-    assert r["ratio"] == pytest.approx(0.5)
+    # 2 GB at the v5e's published 819 GB/s
+    assert r["modeled_ms"] == pytest.approx(2e9 / 819e9 * 1e3)
+    assert r["ratio"] == pytest.approx(10.0 / (2e9 / 819e9 * 1e3))
     assert r["modeled_launches"] == 6
-    text = format_model_comparison(rows, hbm_gbps=100.0)
-    assert "cg_solve" in text and "ratio" in text
+    text = format_model_comparison(rows, device_kind="TPU v5 lite")
+    assert "cg_solve" in text and "ratio" in text and "819" in text
     assert "no phase spans" in format_model_comparison([])
+    # a device kind without published peaks is an error, not a default
+    with pytest.raises(KeyError, match="no published peaks"):
+        phase_model_comparison([span], device_kind="cpu")
 
 
 def test_traced_fit_produces_model_comparison(rng):

@@ -348,7 +348,7 @@ def test_artifact_roundtrip_and_engine_parity(tmp_path):
 
 def test_sharded_blocksparse_matches_dense():
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.core.distributed import make_geometry, replicate, \
         shard_vector
     from repro.sparse import dist_blocksparse_kmvm
@@ -362,7 +362,7 @@ def test_sharded_blocksparse_matches_dense():
         lambda Xr, Vl: dist_blocksparse_kmvm(geom, SPEC, Xr, Vl, params,
                                              plan),
         mesh=mesh, in_specs=(P(), geom.vector_pspec()),
-        out_specs=geom.vector_pspec(), check_rep=False))
+        out_specs=geom.vector_pspec(), check_vma=False))
     out = f(replicate(mesh, Xs), shard_vector(mesh, geom, V))
     ref = dense_khat(SPEC, Xs, params) @ V
     scale = float(jnp.max(jnp.abs(ref)))
