@@ -1,0 +1,10 @@
+"""Share of the training window in which no operation runs on a chip
+(1 - busy / window, busy the union of the device operations' intervals),
+averaged over the chips."""
+
+from chipbench import trace_reduce
+
+
+def read(trace, ctx, lc):
+    share = trace_reduce.idle_share(trace)
+    return None if share is None else 100.0 * share

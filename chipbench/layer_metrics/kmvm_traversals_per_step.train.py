@@ -1,0 +1,16 @@
+"""Full passes of the fused kernel over a chip's tile of K_hat per
+training step, from the trace: the rows its launches computed over the
+tile's rows, averaged over the chips. This is the number of kernel
+traversals the device really executed (the CG loop runs its fixed trip
+count whether or not the columns have converged)."""
+
+from chipbench import counts, trace_reduce
+
+
+def read(trace, ctx, lc):
+    rows = trace_reduce.op_rows(trace, trace_reduce.KMVM)
+    if not lc.get("steps") or not any(rows.values()):
+        return None
+    cfg = ctx.config
+    tile_rows, _ = counts.train_tile(cfg["n"], cfg["mesh"], cfg["mode"])
+    return sum(rows.values()) / len(rows) / tile_rows / lc["steps"]
