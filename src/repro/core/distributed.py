@@ -50,6 +50,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from repro import obs
+from repro.obs.profiling import named_scope
 
 from .kernels_math import (
     constant_mean,
@@ -725,6 +726,16 @@ class DistMLLConfig(NamedTuple):
         )
 
 
+def _aux_tuple(aux) -> tuple:
+    """MLLAux as the (logdet, quad, cg_iterations, rel_residual,
+    traversals) tuple the sharded steps return, all replicated."""
+    return (aux.logdet, aux.quad, aux.cg_iterations, aux.rel_residual,
+            aux.traversals)
+
+
+AUX_SPECS = (P(),) * 5
+
+
 def _dist_mll_forward(geom, cfg, X, y_loc, params, key):
     op = ShardedOperator(cfg.operator_config(geom), X, params)
     (value, aux), (yc, u_y, U, pinv_z), _state = operator_mll_forward(
@@ -733,7 +744,7 @@ def _dist_mll_forward(geom, cfg, X, y_loc, params, key):
         max_cg_iters=cfg.max_cg_iters, min_cg_iters=cfg.min_cg_iters,
         cg_tol=cfg.cg_tol, pcg_method=cfg.pcg_method)
     # plain tuple: shard_map out_specs are written as tuples, not MLLAux
-    aux = (aux.logdet, aux.quad, aux.cg_iterations, aux.rel_residual)
+    aux = _aux_tuple(aux)
     saved = (X, params, yc, u_y, U, pinv_z)
     return (value, aux), saved
 
@@ -751,13 +762,14 @@ def dist_mll_backward(geom, cfg, X, params, u_y, U, pinv_z, g_value):
     # (explicit blockwise tiles, NOT AD through the distributed
     # forward), so the shared Eq. 2 assembly yields partials too
     bwd_cfg = cfg.operator_config(geom)._replace(compute_dtype=None)
-    g_params, g_X = operator_mll_quad_grads(
-        lambda x: ShardedOperator(bwd_cfg, x, params), X, u_y, U, pinv_z)
-    # local partials -> global sums (replicated outputs)
-    g_params = jax.tree.map(lambda a: _psum_all(geom, a), g_params)
-    g_X = _psum_all(geom, g_X)
-    g_params = g_params._replace(
-        raw_mean=g_params.raw_mean + _psum_all(geom, jnp.sum(u_y)))
+    with named_scope("eq2_backward"):
+        g_params, g_X = operator_mll_quad_grads(
+            lambda x: ShardedOperator(bwd_cfg, x, params), X, u_y, U, pinv_z)
+        # local partials -> global sums (replicated outputs)
+        g_params = jax.tree.map(lambda a: _psum_all(geom, a), g_params)
+        g_X = _psum_all(geom, g_X)
+        g_params = g_params._replace(
+            raw_mean=g_params.raw_mean + _psum_all(geom, jnp.sum(u_y)))
     g_params = jax.tree.map(lambda a: g_value * a, g_params)
     g_X = g_value * g_X
     g_y = g_value * (-u_y)
@@ -818,7 +830,7 @@ def make_mll_value_and_grad(mesh: Mesh, geom: DistGeometry, cfg: DistMLLConfig):
     sharded = shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), vec, P(), P()),
-        out_specs=(P(), (P(), P(), P(), P()), P()),
+        out_specs=(P(), AUX_SPECS, P()),
         check_vma=False)
     return jax.jit(sharded)
 
@@ -844,7 +856,7 @@ class DistSolveState(NamedTuple):
 class WarmMLLStepFns(NamedTuple):
     """jit'd step functions returned by `make_warm_mll_step`; all return
     (loss, aux, grads, state) with aux = (logdet, quad, cg_iterations,
-    rel_residual) replicated."""
+    rel_residual, traversals) replicated."""
 
     cold: Callable     # (X, y, params, key)            fresh precond+probes
     refresh: Callable  # (X, y, params, key, state)     fresh precond+probes,
@@ -871,7 +883,6 @@ def make_warm_mll_step(mesh: Mesh, geom: DistGeometry, cfg: DistMLLConfig,
     """
     vec = geom.vector_pspec()
     rep = P()
-    aux_specs = (rep, rep, rep, rep)
     state_specs = DistSolveState(
         solutions=vec, probes=vec,
         precond=DistPreconditioner(L_local=vec, sigma2=rep,
@@ -883,7 +894,8 @@ def make_warm_mll_step(mesh: Mesh, geom: DistGeometry, cfg: DistMLLConfig,
              min_iters):
         op = ShardedOperator(cfg.operator_config(geom), X, params)
         if precond is None:
-            precond = op.preconditioner(cfg.precond_rank)
+            with named_scope("precond_build"):
+                precond = op.preconditioner(cfg.precond_rank)
         (value, aux), (yc, u_y, U, pinv_z), st = operator_mll_forward(
             op, y_loc, key,
             precond_rank=cfg.precond_rank, num_probes=cfg.num_probes,
@@ -895,8 +907,7 @@ def make_warm_mll_step(mesh: Mesh, geom: DistGeometry, cfg: DistMLLConfig,
             geom, cfg, X, params, u_y, U, pinv_z, g_value)
         state = DistSolveState(solutions=st.solutions, probes=st.probes,
                                precond=precond.pre, logdet=aux.logdet)
-        aux_t = (aux.logdet, aux.quad, aux.cg_iterations, aux.rel_residual)
-        return -value / geom.n, aux_t, g_params, state
+        return -value / geom.n, _aux_tuple(aux), g_params, state
 
     def local_cold(X, y_loc, params, key):
         return _run(X, y_loc, params, key, precond=None, probes=None,
@@ -918,7 +929,7 @@ def make_warm_mll_step(mesh: Mesh, geom: DistGeometry, cfg: DistMLLConfig,
                     x0=state.solutions, logdet_carry=state.logdet,
                     min_iters=warm_min_iters)
 
-    out_specs = (rep, aux_specs, rep, state_specs)
+    out_specs = (rep, AUX_SPECS, rep, state_specs)
     cold = jax.jit(shard_map(
         local_cold, mesh=mesh, in_specs=(P(), vec, P(), P()),
         out_specs=out_specs, check_vma=False))

@@ -97,6 +97,8 @@ class MLLAux(NamedTuple):
     # ran with track_residuals=True, else None (None is an empty pytree, so
     # the aux structure — and the compiled program — is unchanged when off).
     residuals: jax.Array | None = None
+    # () int32 operator applications the solve ran (PCGResult.traversals)
+    traversals: jax.Array | None = None
 
 
 def operator_mll_forward(op, y, key, *, precond_rank: int, num_probes: int,
@@ -166,7 +168,7 @@ def operator_mll_forward(op, y, key, *, precond_rank: int, num_probes: int,
     value = -0.5 * (quad + logdet + n * math.log(2.0 * math.pi))
     aux = MLLAux(logdet=logdet, quad=quad,
                  cg_iterations=res.iterations, rel_residual=res.rel_residual,
-                 residuals=res.residuals)
+                 residuals=res.residuals, traversals=res.traversals)
     state = res.state._replace(probes=probes)
     return (value, aux), (yc, u_y, U, pinv_z), state
 

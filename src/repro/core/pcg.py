@@ -52,8 +52,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-# opt-in HLO name scopes (null contexts unless REPRO_OBS_PROFILE is on);
-# device-side accounting leaves via PCGResult.iterations — returned aux,
+# HLO name scopes (op_name metadata: they change no op); device-side
+# accounting leaves via PCGResult.iterations / .traversals — returned aux,
 # never host callbacks on the jit path (see repro.obs)
 from repro.obs.profiling import named_scope
 
@@ -103,6 +103,12 @@ class PCGResult(NamedTuple):
                            #      the SLQ probe norms)
     rel_residual: jax.Array  # (t,) final ||r|| / ||b||
     iterations: jax.Array  # (t,) iterations applied per column
+    # () int32: operator applications (kernel traversals) the solve ran —
+    # one per loop body executed, whatever the columns' convergence, plus
+    # the warm start's residual B - K x0 and the pipelined loop's
+    # pre-loop MVM. Counted in the loop carry, so it stays the executed
+    # count for a loop that exits early.
+    traversals: jax.Array
     # (m, t) per-iteration relative residuals, or None unless the solve
     # was called with track_residuals=True (opt-in: the default scan ys
     # stay (alpha, beta, active), keeping the untracked jaxpr identical).
@@ -232,6 +238,7 @@ def _pcg_standard(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
         return allreduce(jnp.sum(a * b, axis=0))
 
     u, r = _warm_init(mvm, B, x0)
+    traversals = jnp.int32(x0 is not None)
     z = precond_solve(r)
     # reduction 0: <r,z> and <b,b> fused (both available up front)
     init = allreduce(jnp.stack([jnp.sum(r * z, 0), jnp.sum(B * B, 0)]))
@@ -240,7 +247,7 @@ def _pcg_standard(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
     p = z
 
     def body(carry, j):
-        u, r, z, p, rz = carry
+        u, r, z, p, rz, k = carry
         if fused_mvm is None:
             with named_scope("pcg.matvec"):
                 Kp = mvm(p)
@@ -270,17 +277,18 @@ def _pcg_standard(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
         ys = (alpha.astype(dtype), beta.astype(dtype), active)
         if track_residuals:
             ys = ys + (rel.astype(dtype),)
-        return (u, r, z, p, rz), ys
+        return (u, r, z, p, rz, k + 1), ys
 
     from repro.models.runtime_flags import layer_scan_unroll
-    (u, r, _, _, _), ys = jax.lax.scan(
-        body, (u, r, z, p, rz), jnp.arange(max_iters),
+    (u, r, _, _, _, traversals), ys = jax.lax.scan(
+        body, (u, r, z, p, rz, traversals), jnp.arange(max_iters),
         unroll=layer_scan_unroll())
     alphas, betas, actives = ys[:3]
     residuals = ys[3] if track_residuals else None
     rel = jnp.sqrt(vdot(r, r) / b_norm2)
     iters = jnp.sum(actives, axis=0)
-    return PCGResult(u, alphas, betas, actives, rz0, rel, iters, residuals)
+    return PCGResult(u, alphas, betas, actives, rz0, rel, iters, traversals,
+                     residuals)
 
 
 def _pcg_pipelined(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
@@ -311,6 +319,7 @@ def _pcg_pipelined(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
     b_norm2 = jnp.maximum(allreduce(jnp.sum(B * B, 0)), 1e-30)
     u = precond_solve(r)
     w, gamma, delta, rr = mvm_and_reductions(u, r)
+    traversals = jnp.int32(1 + (x0 is not None))
     rz0 = gamma
     p = jnp.zeros_like(B)
     s = jnp.zeros_like(B)
@@ -318,7 +327,8 @@ def _pcg_pipelined(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
     gamma_prev = jnp.ones_like(gamma)
 
     def body(carry, j):
-        x, r, u, w, p, s, gamma, delta, rr, gamma_prev, alpha_prev = carry
+        (x, r, u, w, p, s, gamma, delta, rr, gamma_prev, alpha_prev,
+         k) = carry
         rel = jnp.sqrt(rr / b_norm2)
         active = (rel > tol) | (j < min_iters)
         first = j == 0
@@ -342,18 +352,21 @@ def _pcg_pipelined(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
         ys = (alpha.astype(dtype), beta.astype(dtype), active)
         if track_residuals:
             ys = ys + (rel.astype(dtype),)
-        return ((x, r, u, w, p, s, gamma, delta, rr, gamma_prev_n, alpha_prev_n),
-                ys)
+        return ((x, r, u, w, p, s, gamma, delta, rr, gamma_prev_n, alpha_prev_n,
+                 k + 1), ys)
 
     from repro.models.runtime_flags import layer_scan_unroll
-    carry = (x, r, u, w, p, s, gamma, delta, rr, gamma_prev, alpha_prev)
+    carry = (x, r, u, w, p, s, gamma, delta, rr, gamma_prev, alpha_prev,
+             traversals)
     (x, r, *rest), ys = jax.lax.scan(
         body, carry, jnp.arange(max_iters), unroll=layer_scan_unroll())
+    traversals = rest[-1]
     alphas, betas, actives = ys[:3]
     residuals = ys[3] if track_residuals else None
     rel = jnp.sqrt(allreduce(jnp.sum(r * r, 0)) / b_norm2)
     iters = jnp.sum(actives, axis=0)
-    return PCGResult(x, alphas, betas, actives, rz0, rel, iters, residuals)
+    return PCGResult(x, alphas, betas, actives, rz0, rel, iters, traversals,
+                     residuals)
 
 
 def solve_tolerance_iters(tol: float) -> int:

@@ -246,6 +246,7 @@ def kmvm_pallas_dots(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="kmvm_pallas_dots",
     )(scalars, Xi, Xj, V, Vrow, R)
     return out, dots
 
@@ -324,6 +325,7 @@ def kmvm_pallas_chunk(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="kmvm_pallas_chunk",
     )(scalars, Xi, Xj, V, acc)
 
 
@@ -370,4 +372,5 @@ def kmvm_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="kmvm_pallas",
     )(scalars, Xi, Xj, V)

@@ -43,6 +43,7 @@ from repro.core.kernels_math import (
     normalize_components,
     softplus,
 )
+from repro.obs.profiling import named_scope
 
 from .kmvm import (
     DEFAULT_BM,
@@ -200,15 +201,16 @@ def _run_pass(ppass: _PallasPass, Xi, Xj, V, *, bm, bn, interpret, cdt):
     """One fused Pallas launch; returns the (m, t) fp32 contribution."""
     m, _ = Xi.shape
     n, t = V.shape
-    Xi_s = (Xi / ppass.lengthscale).astype(cdt)
-    Xj_s = (Xj / ppass.lengthscale).astype(cdt)
-    Vs = (ppass.base_weight * V.astype(jnp.float32)).astype(cdt)
-    scalars = _pass_inputs(ppass, cdt)
-
     bm_eff, bn_eff, lane = _tile_geometry(m, n, bm, bn, cdt, interpret)
-    Xi_p = _pad_axis(_pad_axis(Xi_s, 0, bm_eff), 1, lane)
-    Xj_p = _pad_axis(_pad_axis(Xj_s, 0, bn_eff), 1, lane)
-    V_p = _pad_axis(_pad_axis(Vs, 0, bn_eff), 1, lane)
+    # the launch's operands: scaled, cast and padded to the tile geometry
+    with named_scope("kmvm.prep"):
+        Xi_s = (Xi / ppass.lengthscale).astype(cdt)
+        Xj_s = (Xj / ppass.lengthscale).astype(cdt)
+        Vs = (ppass.base_weight * V.astype(jnp.float32)).astype(cdt)
+        scalars = _pass_inputs(ppass, cdt)
+        Xi_p = _pad_axis(_pad_axis(Xi_s, 0, bm_eff), 1, lane)
+        Xj_p = _pad_axis(_pad_axis(Xj_s, 0, bn_eff), 1, lane)
+        V_p = _pad_axis(_pad_axis(Vs, 0, bn_eff), 1, lane)
 
     out = kmvm_pallas(ppass.components, Xi_p, Xj_p, V_p, scalars,
                       bm=bm_eff, bn=bn_eff, interpret=interpret,
@@ -324,18 +326,20 @@ def kmvm_fused_matmat(
             f"{kernel!r} plans to {mvm_plan(kernel, params)}")
     n, _ = X.shape
     t = V.shape[1]
-    Xs = (X / ppass.lengthscale).astype(cdt)
-    Vs = (ppass.base_weight * V.astype(jnp.float32)).astype(cdt)
-    scalars = _pass_inputs(ppass, cdt)
-
     bm_eff, bn_eff, lane = _tile_geometry(n, n, bm, bn, cdt, interpret)
-    Xi_p = _pad_axis(_pad_axis(Xs, 0, bm_eff), 1, lane)
-    Xj_p = _pad_axis(_pad_axis(Xs, 0, bn_eff), 1, lane)
-    V_p = _pad_axis(_pad_axis(Vs, 0, bn_eff), 1, lane)
-    # row views enter UNSCALED and fp32: zero-padded rows contribute zero
-    # to every dot, so the dot block is exact despite row padding
-    Vr_p = _pad_axis(_pad_axis(V.astype(jnp.float32), 0, bm_eff), 1, lane)
-    R_p = _pad_axis(_pad_axis(R.astype(jnp.float32), 0, bm_eff), 1, lane)
+    with named_scope("kmvm.prep"):
+        Xs = (X / ppass.lengthscale).astype(cdt)
+        Vs = (ppass.base_weight * V.astype(jnp.float32)).astype(cdt)
+        scalars = _pass_inputs(ppass, cdt)
+        Xi_p = _pad_axis(_pad_axis(Xs, 0, bm_eff), 1, lane)
+        Xj_p = _pad_axis(_pad_axis(Xs, 0, bn_eff), 1, lane)
+        V_p = _pad_axis(_pad_axis(Vs, 0, bn_eff), 1, lane)
+        # row views enter UNSCALED and fp32: zero-padded rows contribute
+        # zero to every dot, so the dot block is exact despite row padding
+        Vr_p = _pad_axis(_pad_axis(V.astype(jnp.float32), 0, bm_eff), 1,
+                         lane)
+        R_p = _pad_axis(_pad_axis(R.astype(jnp.float32), 0, bm_eff), 1,
+                        lane)
 
     out, dots = kmvm_pallas_dots(
         ppass.components, Xi_p, Xj_p, V_p, Vr_p, R_p, scalars,

@@ -4,12 +4,13 @@ One observability surface for the three questions the paper's timing
 claims force: where did this solve spend its WALL CLOCK (span tracing ->
 `repro.launch.obs_report` per-phase tables), what did it COUNT (metrics
 registry: CG iterations, step modes, autotune hits, sparsity fill, serve
-batch distributions), and what did the DEVICE do (opt-in jax.profiler
-bridge). See the submodule docstrings for the contracts; the headline
-one: everything here is a strict no-op on the default path — tracing off
-means identity-wrapped functions and zero events, metrics touch only
-host code after `block_until_ready`, and nothing ever runs inside jit
-(device values arrive via returned aux).
+batch distributions), and what did the DEVICE do (the jax.profiler
+bridge: HLO phase scopes, always on, and host spans that reach the
+profiler's timeline while it collects). See the submodule docstrings for
+the contracts; the headline one: everything here is a strict no-op on
+the default path — tracing off means identity-wrapped functions and zero
+events, metrics touch only host code after `block_until_ready`, and
+nothing ever runs inside jit (device values arrive via returned aux).
 
     from repro import obs
     with obs.trace_session("trace.jsonl"):
@@ -23,7 +24,7 @@ replans), and `regress` (noise-aware BENCH-JSON diffing behind
 `launch/obs_diff`, the CI perf gate).
 
 Env knobs: REPRO_OBS_TRACE=<path.jsonl> (enable span tracing),
-REPRO_OBS_PROFILE=1 (enable jax.profiler annotations + memory gauges),
+REPRO_OBS_PROFILE=1 (enable jax.profiler step annotations + memory gauges),
 REPRO_OBS_HEALTH=<path.jsonl> (enable the solver health-event sink).
 """
 
@@ -52,12 +53,10 @@ from .metrics import (
     slo,
 )
 from .profiling import (
-    annotate,
     disable_profiling,
     enable_profiling,
     memory_snapshot,
     named_scope,
-    profile_session,
     profiling_enabled,
     step_annotation,
 )
@@ -82,8 +81,8 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "SLOTracker",
     "counter", "gauge", "histogram", "latency_summary",
     "record_solver_step", "registry", "slo",
-    "annotate", "disable_profiling", "enable_profiling", "memory_snapshot",
-    "named_scope", "profile_session", "profiling_enabled", "step_annotation",
+    "disable_profiling", "enable_profiling", "memory_snapshot",
+    "named_scope", "profiling_enabled", "step_annotation",
     "complete_event", "counter_event", "disable_tracing", "drain_events",
     "enable_tracing", "instant", "maybe_wrap", "next_request_id", "span",
     "trace_session", "tracing_enabled",

@@ -353,6 +353,7 @@ def record_solver_step(*, mode: str, iters_per_rhs, drift: float,
                        seconds: float, launches: int | None = None,
                        hbm_bytes: float | None = None,
                        phase_ms: dict | None = None,
+                       traversals: int | None = None,
                        reg: MetricsRegistry | None = None) -> dict:
     """Record one MLL solver step into the registry and return the
     telemetry dict (`GPFitResult.telemetry` entry — shape-compatible
@@ -365,6 +366,10 @@ def record_solver_step(*, mode: str, iters_per_rhs, drift: float,
     (`{"precond_build": .., "cg_solve": .., ...}`) — lands in
     `phase.<name>_ms` histograms and the telemetry entry, the measured
     half that `obs_report --compare-model` sets against the byte model.
+    traversals: the kernel traversals the solve executed
+    (MLLAux.traversals) — the fixed-trip loop runs them whether or not
+    the columns converged, so `cg_iters_max` against `traversals` is the
+    work an early exit would save.
     """
     r = reg if reg is not None else _REGISTRY
     iters = np.asarray(iters_per_rhs).ravel()
@@ -380,9 +385,13 @@ def record_solver_step(*, mode: str, iters_per_rhs, drift: float,
         "refreshed": mode != "warm",
         "cg_iters": total,
         "cg_iters_per_rhs": [int(i) for i in iters],
+        "cg_iters_max": int(iters.max()) if iters.size else 0,
         "drift": drift,
         "seconds": seconds,
     }
+    if traversals is not None:
+        r.counter("cg.traversals").inc(int(traversals))
+        entry["traversals"] = int(traversals)
     if launches is not None:
         r.counter("mvm.matmat_launches").inc(int(launches))
         entry["mvm_launches"] = int(launches)

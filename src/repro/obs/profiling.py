@@ -1,30 +1,25 @@
-"""Opt-in `jax.profiler` integration for the GP spine.
+"""`jax.profiler` integration for the GP spine.
 
 Host spans (`repro.obs.trace`) answer "which phase took how long"; this
-module answers "what did the DEVICE do inside that phase" by bridging to
-JAX's own profiler — strictly opt-in, because profiler annotations, while
-numerically inert, add trace-time metadata and host hooks the default
-path must not pay.
+module answers "what did the DEVICE do inside that phase":
 
-Three surfaces, all no-ops unless `enable_profiling()` ran (or
-`REPRO_OBS_PROFILE=1` / `=logdir` is set in the environment):
-
+* `named_scope(name)` — an HLO name scope around a jit-path phase
+  (`precond_build`, `pcg`, `pcg.matvec`, `slq_logdet`, `eq2_backward`,
+  `kmvm.prep`). Always on: a scope is op_name metadata on the ops it
+  encloses and changes no op, fusion or number, so the profiled program
+  and the timed one are the same program, and a device trace attributes
+  every op to its phase by its op_name.
 * `step_annotation(step)` — `jax.profiler.StepTraceAnnotation` around
   each trainer step, so TensorBoard's trace viewer groups device ops by
   optimizer step (`repro.train.gp_trainer` wraps its full-data steps).
-* `annotate(name)` / `named_scope(name)` — named scopes inside the jit
-  path (`operator_mll_forward`, `pcg`): `jax.named_scope` tags the HLO
-  so profiler timelines and compiled-module dumps show `pcg`,
-  `precond_build`, `slq_logdet`, `eq2_backward` instead of fused-op
-  soup. When disabled this returns a shared null context — the traced
-  jaxpr is byte-identical to the uninstrumented one.
 * `memory_snapshot(tag)` — device memory stats at stage boundaries,
   recorded as `mem.<device_kind>.bytes_in_use` gauges plus a Chrome
   counter event in the active trace (CPU backends without memory_stats
   degrade to a silent no-op).
 
-`profile_session(logdir)` wraps `jax.profiler.start_trace/stop_trace`
-for whole-run device profiles (the TPU-megakernel validation harness).
+The last two are opt-in (`enable_profiling()`, or `REPRO_OBS_PROFILE=1`
+in the environment) and no-ops otherwise. Host spans reach the profiler's
+timeline by themselves while it collects (`repro.obs.trace.span`).
 """
 
 from __future__ import annotations
@@ -32,6 +27,8 @@ from __future__ import annotations
 import contextlib
 import os
 from typing import Any
+
+import jax
 
 from . import metrics, trace
 
@@ -57,30 +54,11 @@ def step_annotation(step: int):
     """StepTraceAnnotation for one trainer step (TensorBoard step grouping)."""
     if not _ENABLED:
         return _NULL
-    import jax
-
     return jax.profiler.StepTraceAnnotation("train_step", step_num=step)
 
 
-def annotate(name: str):
-    """Host-side TraceAnnotation (shows on the profiler's host timeline)."""
-    if not _ENABLED:
-        return _NULL
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
-
-
 def named_scope(name: str):
-    """HLO name scope for jit-path code — `pcg`/`mll` wrap their phases.
-
-    Disabled (default) returns a null context: zero jaxpr/HLO delta, so
-    the golden-pinned traces stay bitwise and nothing retraces.
-    """
-    if not _ENABLED:
-        return _NULL
-    import jax
-
+    """HLO name scope for jit-path code — `pcg`/`mll` wrap their phases."""
     return jax.named_scope(name)
 
 
@@ -93,8 +71,6 @@ def memory_snapshot(tag: str) -> dict[str, Any]:
     """
     if not _ENABLED:
         return {}
-    import jax
-
     out: dict[str, Any] = {}
     for dev in jax.local_devices():
         stats = None
@@ -116,27 +92,6 @@ def memory_snapshot(tag: str) -> dict[str, Any]:
     if out:
         trace.counter_event(f"mem.{tag}", **out)
     return out
-
-
-class profile_session:
-    """`with profile_session(logdir): ...` — a jax.profiler trace around a
-    whole run (device timeline + memory viewer in TensorBoard)."""
-
-    def __init__(self, logdir: str):
-        self.logdir = logdir
-
-    def __enter__(self):
-        import jax
-
-        enable_profiling()
-        jax.profiler.start_trace(self.logdir)
-        return self
-
-    def __exit__(self, *exc):
-        import jax
-
-        jax.profiler.stop_trace()
-        return False
 
 
 _env = os.environ.get("REPRO_OBS_PROFILE")

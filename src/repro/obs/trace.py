@@ -9,8 +9,9 @@ breakdown table.
 
 Design constraints (all load-bearing):
 
-* **Zero overhead when disabled.** `span()` with tracing off returns a
-  shared no-op singleton — no allocation, no time syscall, no lock.
+* **Zero overhead when disabled.** `span()` with tracing and the
+  profiler off returns a shared no-op singleton — no allocation, no time
+  syscall, no lock.
   `maybe_wrap(name, fn)` returns `fn` ITSELF (identity) when tracing is
   off at wrap time, so wrapped hot paths pay literally nothing. The
   default state is disabled; nothing in the repo flips it implicitly.
@@ -20,6 +21,12 @@ Design constraints (all load-bearing):
   recorded into the metrics registry AFTER the step completes. No host
   callbacks, no retraces, no numerics changes (pinned by
   tests/test_obs.py).
+* **On the profiler's clock too.** While `jax.profiler` collects, a span
+  also opens a `jax.profiler.TraceAnnotation` named `repro.<name>` whose
+  stats are the span's attrs (`set()` forwards them), so the host's phases
+  lie on the same timeline as the device ops of a profile. Whether the
+  profiler collects is one `TraceMe.is_enabled()` call; with neither it
+  nor the JSONL sink on, `span()` is still the shared null singleton.
 * **Chrome-compatible events.** One JSON object per line; each span is a
   complete ("ph": "X") event with microsecond ts/dur, pid/tid, and an
   `args` dict. Nesting is implicit in ts/dur containment per tid (how
@@ -46,6 +53,11 @@ import signal
 import threading
 import time
 from typing import Any
+
+from jax.profiler import TraceAnnotation
+
+# the name prefix of a span on the profiler's timeline
+PROFILER_PREFIX = "repro."
 
 
 def _now_us() -> float:
@@ -88,26 +100,37 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """An open span; emits one complete event on exit."""
+    """An open span; emits one complete event on exit to the JSONL sink
+    (when on) and is a profiler annotation (when the profiler collects)."""
 
-    __slots__ = ("name", "args", "_t0")
+    __slots__ = ("name", "args", "_t0", "_tm")
 
     def __init__(self, name: str, args: dict):
         self.name = name
         self.args = args
+        self._tm = (TraceAnnotation(PROFILER_PREFIX + name, **args)
+                    if TraceAnnotation.is_enabled() else None)
         self._t0 = _now_us()
 
     def set(self, **attrs) -> "_Span":
         """Attach attributes discovered mid-span (e.g. iteration counts
         known only after block_until_ready)."""
         self.args.update(attrs)
+        if self._tm is not None:
+            self._tm.set_metadata(**attrs)
         return self
 
     def __enter__(self):
+        if self._tm is not None:
+            self._tm.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = _now_us()
+        if self._tm is not None:
+            self._tm.__exit__(exc_type, exc, tb)
+        if not _STATE.enabled:
+            return False
         _emit({
             "name": self.name,
             "ph": "X",
@@ -136,12 +159,14 @@ def tracing_enabled() -> bool:
 
 
 def span(name: str, **attrs: Any):
-    """Context manager timing a named phase. No-op singleton when disabled.
+    """Context manager timing a named phase. No-op singleton when neither
+    the JSONL sink nor the profiler is on.
 
     Usage: `with obs.span("mll_step", mode="warm") as sp: ...;
-    sp.set(cg_iters=7)` — attrs land in the event's `args`.
+    sp.set(cg_iters=7)` — attrs land in the event's `args`, and in the
+    stats of the profiler's `repro.mll_step` event.
     """
-    if not _STATE.enabled:
+    if not _STATE.enabled and not TraceAnnotation.is_enabled():
         return _NULL_SPAN
     return _Span(name, attrs)
 

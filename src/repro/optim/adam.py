@@ -7,6 +7,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 
 class AdamState(NamedTuple):
     step: jax.Array
@@ -23,32 +25,37 @@ def adam_init(params) -> AdamState:
 def adam_update(params, grads, state: AdamState, lr,
                 b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
                 weight_decay: float = 0.0):
-    """One AdamW step. lr may be a scalar or a callable of the step index."""
-    step = state.step + 1
-    if callable(lr):
-        lr = lr(step)
-    lr = jnp.asarray(lr, jnp.float32)
+    """One AdamW step. lr may be a scalar or a callable of the step index.
 
-    def upd(p, g, m, v):
-        g32 = g.astype(jnp.float32)
-        m = b1 * m + (1 - b1) * g32
-        v = b2 * v + (1 - b2) * g32 * g32
-        mhat = m / (1 - b1 ** step.astype(jnp.float32))
-        vhat = v / (1 - b2 ** step.astype(jnp.float32))
-        delta = mhat / (jnp.sqrt(vhat) + eps)
-        if weight_decay:
-            delta = delta + weight_decay * p.astype(jnp.float32)
-        return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
+    Eager per-leaf ops; an `adam_update` span times them (repro.obs).
+    """
+    with obs.span("adam_update"):
+        step = state.step + 1
+        if callable(lr):
+            lr = lr(step)
+        lr = jnp.asarray(lr, jnp.float32)
 
-    flat_p, tdef = jax.tree.flatten(params)
-    flat_g = tdef.flatten_up_to(grads)
-    flat_m = tdef.flatten_up_to(state.mu)
-    flat_v = tdef.flatten_up_to(state.nu)
-    out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
-    new_p = tdef.unflatten([o[0] for o in out])
-    new_m = tdef.unflatten([o[1] for o in out])
-    new_v = tdef.unflatten([o[2] for o in out])
-    return new_p, AdamState(step=step, mu=new_m, nu=new_v)
+        def upd(p, g, m, v):
+            g32 = g.astype(jnp.float32)
+            m = b1 * m + (1 - b1) * g32
+            v = b2 * v + (1 - b2) * g32 * g32
+            mhat = m / (1 - b1 ** step.astype(jnp.float32))
+            vhat = v / (1 - b2 ** step.astype(jnp.float32))
+            delta = mhat / (jnp.sqrt(vhat) + eps)
+            if weight_decay:
+                delta = delta + weight_decay * p.astype(jnp.float32)
+            return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
+
+        flat_p, tdef = jax.tree.flatten(params)
+        flat_g = tdef.flatten_up_to(grads)
+        flat_m = tdef.flatten_up_to(state.mu)
+        flat_v = tdef.flatten_up_to(state.nu)
+        out = [upd(p, g, m, v)
+               for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
+        new_p = tdef.unflatten([o[0] for o in out])
+        new_m = tdef.unflatten([o[1] for o in out])
+        new_v = tdef.unflatten([o[2] for o in out])
+        return new_p, AdamState(step=step, mu=new_m, nu=new_v)
 
 
 def clip_by_global_norm(grads, max_norm: float):

@@ -200,46 +200,65 @@ class _WarmEngineBase:
 
         The telemetry record is sourced from the obs metrics registry
         (`obs.record_solver_step`) — same keys as the historical bare
-        dicts plus per-RHS iteration counts and the §Roofline-modeled MVM
-        cost. Iteration counts arrive via the RETURNED MLLAux (device
-        aux), never host callbacks; under tracing the step runs through
-        `_dispatch_phased` so the span tree decomposes into phases."""
+        dicts plus per-RHS iteration counts, the most iterations any
+        column applied (`cg_iters_max`), the kernel traversals the solve
+        executed (`traversals`) and the §Roofline-modeled MVM cost.
+        Iteration counts arrive via the RETURNED MLLAux (device aux),
+        never host callbacks; under tracing the step runs through
+        `_dispatch_phased` so the span tree decomposes into phases.
+
+        Spans: `mll_step` (mode, drift, cg_iters, cg_iters_max,
+        traversals) over its children `mll_step.dispatch` (mode decision
+        and the call), `mll_step.wait` (the device's finish and the aux
+        read) and `mll_step.bookkeeping` (health check, state, cost model,
+        registry)."""
         t0 = time.perf_counter()
-        mode, drift = self._mode(params)
-        self._last_phase_ms = None
-        with obs.span("mll_step", mode=mode, drift=float(drift)) as sp:
-            if obs.tracing_enabled():
-                loss, aux, g_params, state = self._dispatch_phased(
-                    mode, X, y, params, key)
-            else:
-                loss, aux, g_params, state = self._dispatch(
-                    mode, X, y, params, key)
-            jax.block_until_ready(loss)
-            iters = np.asarray(aux.cg_iterations)
-            sp.set(cg_iters=int(iters.sum()))
-        # health sentinels run on host-concrete aux, after the fences
-        cfg = getattr(self, "cfg", None)
-        obs_health.check_solver_step(
-            step=len(self.telemetry), mode=mode,
-            tol=float(getattr(cfg, "cg_tol", 1.0)),
-            max_iters=int(getattr(cfg, "max_cg_iters", 100)),
-            iters_per_rhs=iters,
-            rel_residual=np.asarray(aux.rel_residual),
-            residuals=(None if aux.residuals is None
-                       else np.asarray(aux.residuals)),
-            drift=drift)
-        if self.warm.enabled:
-            self.state = state
-            if mode != "warm":
-                self._params_ref = params
-                self._steps_since_refresh = 0
-            self._steps_since_refresh += 1
-        launches, hbm_bytes = self._modeled_cost(mode, X)
-        phase_ms, self._last_phase_ms = self._last_phase_ms, None
-        self.telemetry.append(obs.record_solver_step(
-            mode=mode, iters_per_rhs=iters, drift=drift,
-            seconds=time.perf_counter() - t0,
-            launches=launches, hbm_bytes=hbm_bytes, phase_ms=phase_ms))
+        with obs.span("mll_step") as sp:
+            with obs.span("mll_step.dispatch"):
+                mode, drift = self._mode(params)
+                sp.set(mode=mode, drift=float(drift))
+                self._last_phase_ms = None
+                if obs.tracing_enabled():
+                    loss, aux, g_params, state = self._dispatch_phased(
+                        mode, X, y, params, key)
+                else:
+                    loss, aux, g_params, state = self._dispatch(
+                        mode, X, y, params, key)
+            with obs.span("mll_step.wait"):
+                jax.block_until_ready(loss)
+                # one fetch for the host's reads of aux (None stays None)
+                iters, rel_residual, traversals = jax.device_get(
+                    (aux.cg_iterations, aux.rel_residual, aux.traversals))
+                iters = np.asarray(iters)
+                if traversals is not None:
+                    traversals = int(traversals)
+            sp.set(cg_iters=int(iters.sum()), cg_iters_max=int(iters.max()),
+                   traversals=traversals)
+            with obs.span("mll_step.bookkeeping"):
+                # health sentinels run on host-concrete aux, after the fences
+                cfg = getattr(self, "cfg", None)
+                obs_health.check_solver_step(
+                    step=len(self.telemetry), mode=mode,
+                    tol=float(getattr(cfg, "cg_tol", 1.0)),
+                    max_iters=int(getattr(cfg, "max_cg_iters", 100)),
+                    iters_per_rhs=iters,
+                    rel_residual=np.asarray(rel_residual),
+                    residuals=(None if aux.residuals is None
+                               else np.asarray(aux.residuals)),
+                    drift=drift)
+                if self.warm.enabled:
+                    self.state = state
+                    if mode != "warm":
+                        self._params_ref = params
+                        self._steps_since_refresh = 0
+                    self._steps_since_refresh += 1
+                launches, hbm_bytes = self._modeled_cost(mode, X)
+                phase_ms, self._last_phase_ms = self._last_phase_ms, None
+                self.telemetry.append(obs.record_solver_step(
+                    mode=mode, iters_per_rhs=iters, drift=drift,
+                    seconds=time.perf_counter() - t0,
+                    launches=launches, hbm_bytes=hbm_bytes,
+                    phase_ms=phase_ms, traversals=traversals))
         return loss, aux, g_params
 
     def extend_rows(self, m: int) -> None:
@@ -488,7 +507,7 @@ class WarmStartEngine(_WarmEngineBase):
         aux = MLLAux(logdet=logdet, quad=quad,
                      cg_iterations=res.iterations,
                      rel_residual=res.rel_residual,
-                     residuals=res.residuals)
+                     residuals=res.residuals, traversals=res.traversals)
         new_state = SolverState(solve=res.state._replace(probes=probes),
                                 precond=precond, logdet=logdet)
         return -value / n, aux, g_params, new_state
@@ -499,8 +518,8 @@ class DistWarmStartEngine(_WarmEngineBase):
 
     Wraps `repro.core.distributed.make_warm_mll_step`; the refresh schedule
     and telemetry come from the shared base. aux comes back as the
-    (logdet, quad, cg_iterations, rel_residual) tuple the distributed MLL
-    uses, repacked into MLLAux here.
+    (logdet, quad, cg_iterations, rel_residual, traversals) tuple the
+    distributed MLL uses, repacked into MLLAux here.
     """
 
     def __init__(self, mesh, geom, cfg, warm: WarmStartConfig | None = None):
@@ -524,5 +543,6 @@ class DistWarmStartEngine(_WarmEngineBase):
             out = self._fns.warm(X, y, params_r, key, self.state)
         loss, aux_t, g_params, state = out
         aux = MLLAux(logdet=aux_t[0], quad=aux_t[1],
-                     cg_iterations=aux_t[2], rel_residual=aux_t[3])
+                     cg_iterations=aux_t[2], rel_residual=aux_t[3],
+                     traversals=aux_t[4])
         return loss, aux, g_params, state
