@@ -75,6 +75,9 @@ class ReferenceTrainer:
     tolerance, at most `train_cg_iters` iterations), plain Adam."""
 
     precision = "highest"
+    # its solves are not the program's, so `train_mfu` has no count of
+    # the iterations a step needed to charge, and reads nothing
+    cg_iters_max = None
 
     def __init__(self, ctx, X, y):
         from chipbench.kinds import train

@@ -45,10 +45,12 @@ def train_tile(n: int, mesh: tuple, mode: str) -> tuple[int, int]:
 
 def train_traversals(mode: str, cg_iters: int) -> int:
     """Full passes over the n x n kernel matrix in one BBMM training step:
-    `cg_iters` CG matvecs (the loop runs its fixed trip count), one more to
-    form the residual of a warm start (warm and refresh steps), and one
-    for the Eq. 2 backward, which contracts the same entries against the
-    solutions."""
+    `cg_iters` CG matvecs, one more to form the residual of a warm start
+    (warm and refresh steps), and one for the Eq. 2 backward, which
+    contracts the same entries against the solutions. `train_mfu` passes
+    the iterations the step's solve needed; a fixed-trip loop executes
+    its whole trip count, which `kmvm_traversals_per_step.train` reads
+    from the device."""
     if mode not in ("cold", "warm", "refresh"):
         raise ValueError(f"unknown solve mode {mode!r}")
     return cg_iters + (0 if mode == "cold" else 1) + 1

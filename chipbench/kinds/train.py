@@ -178,7 +178,9 @@ class ProgramTrainer:
         with annotate("loss_to_host", trace):
             loss = float(loss)
             grads = jax.device_get(grads)
-        mode = self.engine.telemetry[-1]["mode"]
+        tel = self.engine.telemetry[-1]
+        mode = tel["mode"]
+        self.cg_iters_max = tel["cg_iters_max"]
         finite = bool(np.isfinite(loss)) and all(
             np.all(np.isfinite(g)) for g in jax.tree.leaves(grads))
         rec = None
@@ -227,12 +229,14 @@ def setup(ctx, X, y, trainer_cls=ProgramTrainer):
 
 def window(ctx, trainer, first_step: int):
     """Whole steps until ctx.seconds have passed: (steps, seconds, modes,
-    failed, the records of the last `window_checked_steps`)."""
+    the most CG iterations any column of each step applied (None where
+    the trainer does not count them), failed, the records of the last
+    `window_checked_steps`)."""
     import jax
 
     base = datagen.seed_key(ctx.seed)
     keep = ctx.traffic["window_checked_steps"]
-    modes, failed, kept = [], 0, []
+    modes, iters, failed, kept = [], [], 0, []
     i = first_step
     t0 = time.perf_counter()
     deadline = t0 + ctx.seconds
@@ -243,11 +247,12 @@ def window(ctx, trainer, first_step: int):
             rec.step = i
             kept = (kept + [rec])[-keep:]
         modes.append(mode)
+        iters.append(trainer.cg_iters_max)
         failed += not finite
         i += 1
         if time.perf_counter() >= deadline:
             break
-    return len(modes), time.perf_counter() - t0, modes, failed, kept
+    return len(modes), time.perf_counter() - t0, modes, iters, failed, kept
 
 
 def leaf_gap(got, want, scale) -> float:
@@ -330,9 +335,10 @@ def run(ctx, clock, trainer_cls=ProgramTrainer) -> dict:
     clock.mark()
     prof = Profile(ctx)
     with prof:
-        steps, secs, modes, failed, last = window(ctx, trainer, n_setup)
+        steps, secs, modes, iters, failed, last = window(ctx, trainer,
+                                                         n_setup)
     log(f"[window] {steps} steps in {secs:.3f}s modes={modes} "
-        f"compiles_in_window={clock.since_mark}")
+        f"cg_iters_max={iters} compiles_in_window={clock.since_mark}")
     mem = memory_peak_bytes(ctx.devices)
     trainer.free()
     del trainer
@@ -349,5 +355,6 @@ def run(ctx, clock, trainer_cls=ProgramTrainer) -> dict:
         "checks": checks, "memory_peak_bytes": mem,
         "profile": prof.path,
         "layer_ctx": {"kind": "train", "steps": steps, "modes": modes,
-                      "window_host_s": secs, "step_s": secs / steps},
+                      "cg_iters": iters, "window_host_s": secs,
+                      "step_s": secs / steps},
     }

@@ -45,6 +45,10 @@ def test_train_sound_run_is_correct():
     # the set-up's three steps and the window's last ones were checked
     assert set(out["checks"].as_dict()) == {"resid_gap", "grad_gap",
                                             "adam_gap"}
+    # each window step left the iterations its solve needed for train_mfu
+    lc = out["layer_ctx"]
+    assert len(lc["cg_iters"]) == lc["steps"]
+    assert all(1 <= i <= 20 for i in lc["cg_iters"]), lc["cg_iters"]
 
 
 @pytest.mark.parametrize("fault", ["unchanged", "half_rows", "altered",
