@@ -4,7 +4,7 @@ This is the million-point recipe at demo scale: the same
 `repro.core.distributed` engine the multi-pod dry-run lowers at n = 2^20 on
 512 chips, here executed for real on 8 fake CPU devices at n = 8192 —
 row-sharded kernel partitions, distributed pivoted-Cholesky preconditioner,
-fixed-trip PCG with convergence masking, custom-VJP hyperparameter
+PCG with convergence masking and an early exit, custom-VJP hyperparameter
 gradients, tight-tolerance distributed mean-cache solve, then sub-second
 single-device predictions from the cache (paper Table 2 pattern) — and
 finally the mesh-solved posterior saved as a `repro.serve` artifact and

@@ -17,10 +17,19 @@ Two loop structures:
     engine this halves the blocking collective count (beyond-paper
     optimization; see EXPERIMENTS.md §Perf).
 
-The loops use a fixed trip count (`lax.scan`) with per-column convergence
-masking instead of a data-dependent while_loop: on a 256-chip mesh every
-device executes the same schedule (no ragged iteration counts -> no
-stragglers), and the compiled HLO is identical across steps.
+Each loop is a `lax.while_loop` over at most `max_iters` iterations with
+per-column convergence masking: a converged column's alpha and beta are 0,
+so it stays frozen while the others iterate, and the loop leaves as soon
+as no column is active (`_masked_loop`). That exit changes no output: an
+iteration in which every column is inactive leaves the state as it was
+and records alpha = beta = 0, active = False — exactly what the rows of
+the preallocated `(max_iters, t)` records already hold — so the solution
+and the coefficient traces are bitwise those of the whole trip count;
+only `PCGResult.traversals` reads fewer. The exit predicate is formed from
+the all-reduced norms (`allreduce` of ||r||^2, or rr), so on a mesh every
+device computes the same predicate and leaves at the same iteration: no
+ragged iteration counts, no stragglers, and the compiled HLO is
+identical across steps.
 
 The solver is warm-startable: `pcg(..., x0=...)` seeds the iteration with a
 previous solution (r0 = B - K x0, one extra MVM), and `PCGResult.state` is a
@@ -104,14 +113,14 @@ class PCGResult(NamedTuple):
     rel_residual: jax.Array  # (t,) final ||r|| / ||b||
     iterations: jax.Array  # (t,) iterations applied per column
     # () int32: operator applications (kernel traversals) the solve ran —
-    # one per loop body executed, whatever the columns' convergence, plus
-    # the warm start's residual B - K x0 and the pipelined loop's
-    # pre-loop MVM. Counted in the loop carry, so it stays the executed
-    # count for a loop that exits early.
+    # one per loop body executed, plus the warm start's residual B - K x0
+    # and the pipelined loop's pre-loop MVM. Counted in the loop carry, so
+    # it is the executed count: the loop leaves once no column is active.
     traversals: jax.Array
     # (m, t) per-iteration relative residuals, or None unless the solve
-    # was called with track_residuals=True (opt-in: the default scan ys
-    # stay (alpha, beta, active), keeping the untracked jaxpr identical).
+    # was called with track_residuals=True (opt-in: by default the loop
+    # records only (alpha, beta, active), keeping the untracked jaxpr
+    # identical). Rows past the loop's exit repeat the frozen value.
     # This is the health-monitor feed (repro.obs.health): stagnation /
     # divergence sentinels read the trajectory, not just the endpoint.
     residuals: jax.Array | None = None
@@ -170,10 +179,10 @@ def pcg(
         fallback is numerically the same reductions); False forces the
         classic body. Bare-callable A always runs the classic body
         bitwise-unchanged — the golden-pinned trace.
-      track_residuals: stack the per-iteration relative residuals into
-        `PCGResult.residuals` (an extra (max_iters, t) scan output). The
+      track_residuals: record the per-iteration relative residuals in
+        `PCGResult.residuals` (an extra (max_iters, t) loop record). The
         residual norms are already computed every iteration for the
-        convergence mask, so tracking adds only the stacked output — but
+        convergence mask, so tracking adds only the extra record — but
         it DOES change the compiled program, so it is off by default and
         the False path's jaxpr is byte-identical to the pre-tracking one
         (pinned by tests/test_obs_v2.py).
@@ -230,6 +239,62 @@ def _warm_init(mvm, B, x0):
     return x0, B - mvm(x0)
 
 
+def _masked_loop(step, more, state, ref, max_iters, dtype, track_residuals):
+    """Run `step` for at most `max_iters` iterations, leaving once `more`
+    turns False.
+
+    `step(state, j) -> (state, rows)` runs iteration j. `rows` is its
+    (alpha, beta, active[, rel]) record, written to row j of preallocated
+    `(max_iters, t)` buffers; rows of iterations never run keep alpha =
+    beta = 0 and active = False, what an iteration with every column
+    inactive records. `more(state, j)` says whether iteration j can have
+    an active column; it runs in the loop's condition, apart from the
+    body, so it adds no consumer to the body's reductions (which could
+    change how the compiler fuses them, and so their rounding). `ref` is
+    a (t,) reduction formed before the loop: the records take its type,
+    sharding included, as the rows written into them have it. Returns
+    (state, records, iterations run).
+    """
+    from repro.models.runtime_flags import layer_scan_unroll
+    # the dry-run compiles the body with 1 and with 2 iterations in it to
+    # extrapolate its cost; an iteration run past the exit is fully
+    # masked, so the second changes no output
+    unroll = layer_scan_unroll()
+    if max_iters % unroll:
+        unroll = 1
+
+    def record(dt):
+        return jnp.broadcast_to(jnp.zeros_like(ref, dt)[None],
+                                (max_iters,) + ref.shape)
+
+    recs = (record(dtype), record(dtype), record(bool))
+    if track_residuals:
+        recs = recs + (record(dtype),)
+
+    def cond(carry):
+        j, state, _ = carry
+        return (j < max_iters) & more(state, j)
+
+    def body(carry):
+        j, state, recs = carry
+        for _ in range(unroll):
+            state, rows = step(state, j)
+            recs = tuple(jax.lax.dynamic_update_index_in_dim(buf, row, j, 0)
+                         for buf, row in zip(recs, rows))
+            j = j + 1
+        return j, state, recs
+
+    ran, state, recs = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), state, recs))
+    return state, recs, ran
+
+
+def _fill_rows(buf, ran, row):
+    """`buf` with every row from `ran` on set to `row`."""
+    kept = jnp.arange(buf.shape[0])[:, None] < ran
+    return jnp.where(kept, buf, row[None, :])
+
+
 def _pcg_standard(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
                   x0=None, fused_mvm=None, track_residuals=False):
     dtype = B.dtype
@@ -246,8 +311,8 @@ def _pcg_standard(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
     rz0 = rz
     p = z
 
-    def body(carry, j):
-        u, r, z, p, rz, k = carry
+    def step(carry, j):
+        u, r, z, p, rz, k, _ = carry
         if fused_mvm is None:
             with named_scope("pcg.matvec"):
                 Kp = mvm(p)
@@ -274,17 +339,28 @@ def _pcg_standard(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
         p = jnp.where(active, z_new + beta * p, p)
         z = jnp.where(active, z_new, z)
         rz = jnp.where(active, rz_new, rz)
-        ys = (alpha.astype(dtype), beta.astype(dtype), active)
+        rows = (alpha.astype(dtype), beta.astype(dtype), active)
         if track_residuals:
-            ys = ys + (rel.astype(dtype),)
-        return (u, r, z, p, rz, k + 1), ys
+            rows = rows + (rel.astype(dtype),)
+        return (u, r, z, p, rz, k + 1, active), rows
 
-    from repro.models.runtime_flags import layer_scan_unroll
-    (u, r, _, _, _, traversals), ys = jax.lax.scan(
-        body, (u, r, z, p, rz, traversals), jnp.arange(max_iters),
-        unroll=layer_scan_unroll())
-    alphas, betas, actives = ys[:3]
-    residuals = ys[3] if track_residuals else None
+    def more(carry, j):
+        # ||r||^2 comes out of an iteration's MVM launch, so the loop
+        # learns that every column is done only after running it
+        return jnp.any(carry[-1])
+
+    carry = (u, r, z, p, rz, traversals, jnp.ones_like(rz, bool))
+    (u, r, _, _, _, traversals, _), recs, ran = _masked_loop(
+        step, more, carry, rz0, max_iters, dtype, track_residuals)
+    alphas, betas, actives = recs[:3]
+    residuals = None
+    if track_residuals:
+        residuals = recs[3]
+        if max_iters:
+            # the loop left after an iteration with no column active: r
+            # stands still from there, so each skipped row repeats the last
+            residuals = _fill_rows(residuals, ran,
+                                   residuals[jnp.maximum(ran - 1, 0)])
     rel = jnp.sqrt(vdot(r, r) / b_norm2)
     iters = jnp.sum(actives, axis=0)
     return PCGResult(u, alphas, betas, actives, rz0, rel, iters, traversals,
@@ -326,11 +402,19 @@ def _pcg_pipelined(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
     alpha_prev = jnp.ones_like(gamma)
     gamma_prev = jnp.ones_like(gamma)
 
-    def body(carry, j):
+    def mask(rr, j):
+        rel = jnp.sqrt(rr / b_norm2)
+        return rel, (rel > tol) | (j < min_iters)
+
+    def more(carry, j):
+        # the mask needs only the carried rr, so the loop leaves before
+        # launching an MVM that no column would use
+        return jnp.any(mask(carry[8], j)[1])
+
+    def step(carry, j):
         (x, r, u, w, p, s, gamma, delta, rr, gamma_prev, alpha_prev,
          k) = carry
-        rel = jnp.sqrt(rr / b_norm2)
-        active = (rel > tol) | (j < min_iters)
+        rel, active = mask(rr, j)
         first = j == 0
         beta = jnp.where(first, 0.0, _safe_div(gamma, gamma_prev))
         denom = delta - beta * gamma / jnp.where(first, 1.0, alpha_prev)
@@ -349,20 +433,23 @@ def _pcg_pipelined(mvm, B, precond_solve, max_iters, min_iters, tol, allreduce,
         gamma = jnp.where(active, gamma_new, gamma)
         delta = jnp.where(active, delta_new, delta)
         rr = jnp.where(active, rr_new, rr)
-        ys = (alpha.astype(dtype), beta.astype(dtype), active)
+        rows = (alpha.astype(dtype), beta.astype(dtype), active)
         if track_residuals:
-            ys = ys + (rel.astype(dtype),)
-        return ((x, r, u, w, p, s, gamma, delta, rr, gamma_prev_n, alpha_prev_n,
-                 k + 1), ys)
+            rows = rows + (rel.astype(dtype),)
+        return ((x, r, u, w, p, s, gamma, delta, rr, gamma_prev_n,
+                 alpha_prev_n, k + 1), rows)
 
-    from repro.models.runtime_flags import layer_scan_unroll
     carry = (x, r, u, w, p, s, gamma, delta, rr, gamma_prev, alpha_prev,
              traversals)
-    (x, r, *rest), ys = jax.lax.scan(
-        body, carry, jnp.arange(max_iters), unroll=layer_scan_unroll())
-    traversals = rest[-1]
-    alphas, betas, actives = ys[:3]
-    residuals = ys[3] if track_residuals else None
+    (x, r, _, _, _, _, _, _, rr, _, _, traversals), recs, ran = _masked_loop(
+        step, more, carry, rz0, max_iters, dtype, track_residuals)
+    alphas, betas, actives = recs[:3]
+    residuals = None
+    if track_residuals:
+        # each skipped row would record the rel of the carried rr, which
+        # no longer changes
+        residuals = _fill_rows(recs[3], ran,
+                               mask(rr, ran)[0].astype(dtype))
     rel = jnp.sqrt(allreduce(jnp.sum(r * r, 0)) / b_norm2)
     iters = jnp.sum(actives, axis=0)
     return PCGResult(x, alphas, betas, actives, rz0, rel, iters, traversals,

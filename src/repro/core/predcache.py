@@ -330,19 +330,15 @@ def _pcg_block_jit(op, B, precond, x0, *, max_iters, min_iters, tol):
 
 
 def _pcg_blocked(op, B, precond, *, tol, max_iters, min_iters, block, x0):
-    """Host-paced PCG: fixed-trip `block`-iteration scans with a
-    convergence check between blocks.
+    """Host-paced PCG: `block`-iteration solves with a convergence check
+    between blocks.
 
-    `pcg`'s fixed trip count is the right shape for training (every mesh
-    device runs the same schedule, converged columns are merely masked),
-    but it makes wall-clock INDEPENDENT of the start — a warm solve that
-    converges in 5 iterations still pays max_iters MVMs. The streaming
-    update runs on the host (serving is eager and latency-sensitive), so
-    here the schedule is data-dependent: run one small fixed-shape block
-    (`_pcg_block_jit`), sync the relative residual, stop when it clears
-    `tol`. Each block warm-starts from the previous block's solution —
-    mathematically the same iterate sequence, paying at most `block - 1`
-    wasted MVMs.
+    Run one small fixed-shape block (`_pcg_block_jit`), sync the
+    relative residual, stop when it clears `tol`. Each block warm-starts
+    from the previous block's solution — mathematically the same iterate
+    sequence. `pcg` itself leaves its loop once no column is active, so
+    the blocks save no MVMs over one `pcg` call; they are left for a
+    simplification to remove.
 
     Returns (last block's PCGResult, total iterations applied per column).
     """

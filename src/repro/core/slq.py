@@ -18,8 +18,9 @@ which is exactly the first <r, z> of the PCG run (PCGResult.rz0). Hence
 
 logdet(P) comes in closed form from the pivoted-Cholesky factor
 (`Preconditioner.logdet`). Converged-and-frozen CG iterations are patched to
-identity rows of T (log contribution 0), so the fixed-trip-count scan needs
-no ragged handling.
+identity rows of T (log contribution 0), and so are the rows the CG loop
+never ran after its early exit (their records hold alpha = beta = 0,
+active = False): the (max_iters, t) records need no ragged handling.
 """
 
 from __future__ import annotations

@@ -15,13 +15,14 @@ solve cost" without instrumenting the jit path:
 * blocksparse: the partitioned accounting scaled by the plan's fill
   ratio (work and traffic are pair-proportional by construction).
 
-The CG scan has a FIXED trip count (`lax.scan` over max_iters with
-convergence masking — see `repro.core.pcg`), so the compiled program
-executes max_iters kernel traversals regardless of when columns converge;
-the model charges exactly that (plus one warm-init MVM when x0 is
-seeded). Converged-column masking saves flops via masked updates, not
-traversals. The Eq. 2 backward adds ~2.5 slab-equivalent traversals over
-the merged (t+1)-column quad-form chain (§Roofline "backward accounting").
+The model charges the CG loop's whole trip count: max_iters forward
+traversals (plus one warm-init MVM when x0 is seeded). That is an upper
+bound: the loop (`repro.core.pcg`) leaves once no column is active, so
+the traversals a solve executes are fewer, and the engine records them,
+measured, as `traversals` and the difference as `traversals_saved`
+(`repro.obs.metrics.record_solver_step`). The Eq. 2 backward adds ~2.5
+slab-equivalent traversals over the merged (t+1)-column quad-form chain
+(§Roofline "backward accounting").
 
 These are MODELED numbers — a consistent cost ruler across steps and
 backends, not measured hardware counters. `obs_report` labels them so.

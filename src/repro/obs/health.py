@@ -1,7 +1,7 @@
 """Solver health events: structured JSONL sentinels for sick solves.
 
-The BBMM training loop can fail *quietly*: CG burns its full fixed trip
-count without converging, the residual stagnates against a stale
+The BBMM training loop can fail *quietly*: CG runs all max_iters
+iterations without converging, the residual stagnates against a stale
 preconditioner, bf16 compute overflows into NaN, or the blocksparse plan
 drifts out of date — and the optimizer keeps stepping on garbage
 gradients. This module turns those conditions into explicit, structured
@@ -29,7 +29,7 @@ Event kinds emitted by the repo:
 
   cg.nan          non-finite residual/solution — the step's gradients are
                   garbage (severity=error)
-  cg.max_iters    CG exhausted the fixed trip count with rel > tol
+  cg.max_iters    CG ran all max_iters iterations with rel > tol
   cg.divergence   residual grew over the trajectory (late >> early)
   cg.stagnation   windowed improvement ratio ~1 while unconverged —
                   classic stale-preconditioner signature

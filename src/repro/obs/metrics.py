@@ -354,6 +354,7 @@ def record_solver_step(*, mode: str, iters_per_rhs, drift: float,
                        hbm_bytes: float | None = None,
                        phase_ms: dict | None = None,
                        traversals: int | None = None,
+                       traversals_saved: int | None = None,
                        reg: MetricsRegistry | None = None) -> dict:
     """Record one MLL solver step into the registry and return the
     telemetry dict (`GPFitResult.telemetry` entry — shape-compatible
@@ -367,9 +368,12 @@ def record_solver_step(*, mode: str, iters_per_rhs, drift: float,
     `phase.<name>_ms` histograms and the telemetry entry, the measured
     half that `obs_report --compare-model` sets against the byte model.
     traversals: the kernel traversals the solve executed
-    (MLLAux.traversals) — the fixed-trip loop runs them whether or not
-    the columns converged, so `cg_iters_max` against `traversals` is the
-    work an early exit would save.
+    (MLLAux.traversals). The CG loop leaves once no column is active, so
+    the standard loop runs `cg_iters_max` + 1 bodies (the last finds
+    every column done), the pipelined loop `cg_iters_max`.
+    traversals_saved: the traversals the whole trip count would have run
+    (max_cg_iters, + 1 with a warm start, + 1 for the pipelined loop's
+    pre-loop MVM) minus `traversals` — what the early exit left out.
     """
     r = reg if reg is not None else _REGISTRY
     iters = np.asarray(iters_per_rhs).ravel()
@@ -392,6 +396,9 @@ def record_solver_step(*, mode: str, iters_per_rhs, drift: float,
     if traversals is not None:
         r.counter("cg.traversals").inc(int(traversals))
         entry["traversals"] = int(traversals)
+    if traversals_saved is not None:
+        r.counter("cg.traversals_saved").inc(int(traversals_saved))
+        entry["traversals_saved"] = int(traversals_saved)
     if launches is not None:
         r.counter("mvm.matmat_launches").inc(int(launches))
         entry["mvm_launches"] = int(launches)

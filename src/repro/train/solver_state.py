@@ -183,6 +183,15 @@ class _WarmEngineBase:
         except (AttributeError, TypeError, ValueError):
             return None, None
 
+    def _trip_traversals(self, mode) -> int:
+        """The traversals the CG loop's whole trip count would run: every
+        iteration, the warm start's residual and the pipelined loop's
+        pre-loop MVM (`PCGResult.traversals` of a solve that never
+        exits early)."""
+        cfg = self.cfg
+        return (int(cfg.max_cg_iters) + (mode != "cold")
+                + (cfg.pcg_method == "pipelined"))
+
     def _mode(self, params) -> tuple[str, float]:
         if self.state is None or not self.warm.enabled:
             return "cold", 0.0
@@ -202,16 +211,18 @@ class _WarmEngineBase:
         (`obs.record_solver_step`) — same keys as the historical bare
         dicts plus per-RHS iteration counts, the most iterations any
         column applied (`cg_iters_max`), the kernel traversals the solve
-        executed (`traversals`) and the §Roofline-modeled MVM cost.
+        executed (`traversals`), those its early exit left out of the
+        whole trip count (`traversals_saved`) and the §Roofline-modeled
+        MVM cost.
         Iteration counts arrive via the RETURNED MLLAux (device aux),
         never host callbacks; under tracing the step runs through
         `_dispatch_phased` so the span tree decomposes into phases.
 
         Spans: `mll_step` (mode, drift, cg_iters, cg_iters_max,
-        traversals) over its children `mll_step.dispatch` (mode decision
-        and the call), `mll_step.wait` (the device's finish and the aux
-        read) and `mll_step.bookkeeping` (health check, state, cost model,
-        registry)."""
+        traversals, traversals_saved) over its children
+        `mll_step.dispatch` (mode decision and the call), `mll_step.wait`
+        (the device's finish and the aux read) and `mll_step.bookkeeping`
+        (health check, state, cost model, registry)."""
         t0 = time.perf_counter()
         with obs.span("mll_step") as sp:
             with obs.span("mll_step.dispatch"):
@@ -230,10 +241,12 @@ class _WarmEngineBase:
                 iters, rel_residual, traversals = jax.device_get(
                     (aux.cg_iterations, aux.rel_residual, aux.traversals))
                 iters = np.asarray(iters)
+                saved = None
                 if traversals is not None:
                     traversals = int(traversals)
+                    saved = self._trip_traversals(mode) - traversals
             sp.set(cg_iters=int(iters.sum()), cg_iters_max=int(iters.max()),
-                   traversals=traversals)
+                   traversals=traversals, traversals_saved=saved)
             with obs.span("mll_step.bookkeeping"):
                 # health sentinels run on host-concrete aux, after the fences
                 cfg = getattr(self, "cfg", None)
@@ -258,7 +271,8 @@ class _WarmEngineBase:
                     mode=mode, iters_per_rhs=iters, drift=drift,
                     seconds=time.perf_counter() - t0,
                     launches=launches, hbm_bytes=hbm_bytes,
-                    phase_ms=phase_ms, traversals=traversals))
+                    phase_ms=phase_ms, traversals=traversals,
+                    traversals_saved=saved))
         return loss, aux, g_params
 
     def extend_rows(self, m: int) -> None:

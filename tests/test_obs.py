@@ -184,7 +184,7 @@ def test_cost_model_backends():
     n, d, r, iters = 1024, 4, 5, 20
     part = obs.mll_step_cost(n, d, r, iters, backend="partitioned",
                              row_block=256)
-    # fixed trip count: max_iters forward traversals, 4 slabs each
+    # the whole trip count: max_iters forward traversals, 4 slabs each
     assert part.launches == iters * 4 + 4
     assert part.hbm_bytes == pytest.approx(
         n * n * 8.0 * iters + n * n * 8.0 * 2.5)

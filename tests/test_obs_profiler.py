@@ -184,7 +184,15 @@ def test_pcg_traversals_count_the_matvecs_executed(rng, method, warm):
     res = jax.jit(lambda B, x0: pcg(mvm, B, max_iters=MAX_ITERS,
                                     method=method, x0=x0))(B, x0)
     jax.block_until_ready(res.solution)
-    expect = MAX_ITERS + (method == "pipelined") + warm
+    # the loop leaves once no column is active: the standard loop learns
+    # it from the iteration's own MVM (one body more than the most
+    # iterations a column applied), the pipelined loop before launching
+    # it; a warm start's residual and the pipelined pre-loop MVM add one
+    most = int(np.max(np.asarray(res.iterations)))
+    if method == "standard":
+        expect = min(most + 1, MAX_ITERS) + warm
+    else:
+        expect = most + 1 + warm
     assert res.traversals.dtype == jnp.int32
     assert int(res.traversals) == len(calls) == expect
 
@@ -205,17 +213,26 @@ def test_traversals_reach_the_mll_step_span_and_telemetry(rng):
     steps = [e for e in events if e["name"] == "mll_step"]
     modes = [t["mode"] for t in eng.telemetry]
     assert modes == ["cold", "warm", "refresh"]
-    # the fixed-trip loop runs max_iters bodies; a warm start's residual
-    # B - K x0 is one traversal more
-    expect = [MAX_ITERS + (m != "cold") for m in modes]
+    # the standard loop leaves after the first iteration in which no
+    # column was active (at most max_iters bodies); a warm start's
+    # residual B - K x0 is one traversal more
+    most = [int(np.max(np.asarray(a.cg_iterations))) for a in auxes]
+    expect = [min(it + 1, MAX_ITERS) + (m != "cold")
+              for it, m in zip(most, modes)]
     assert [int(a.traversals) for a in auxes] == expect
     assert [t["traversals"] for t in eng.telemetry] == expect
     assert [e["args"]["traversals"] for e in steps] == expect
+    # what the exit left out of the whole trip count
+    saved = [MAX_ITERS + (m != "cold") - e for m, e in zip(modes, expect)]
+    assert [t["traversals_saved"] for t in eng.telemetry] == saved
+    assert [e["args"]["traversals_saved"] for e in steps] == saved
     for t, e, aux in zip(eng.telemetry, steps, auxes):
         most = int(np.max(np.asarray(aux.cg_iterations)))
         assert t["cg_iters_max"] == e["args"]["cg_iters_max"] == most
         assert e["args"]["mode"] == t["mode"]
-    assert obs.registry().snapshot()["cg.traversals"] == sum(expect)
+    snap = obs.registry().snapshot()
+    assert snap["cg.traversals"] == sum(expect)
+    assert snap["cg.traversals_saved"] == sum(saved)
     # each step's three children lie inside its span
     for name in ("mll_step.dispatch", "mll_step.wait",
                  "mll_step.bookkeeping"):

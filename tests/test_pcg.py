@@ -397,3 +397,230 @@ def test_operator_solve_matches_direct(rng):
         res = pcg(op, B, None, max_iters=200, min_iters=3, tol=1e-7)
         np.testing.assert_allclose(np.asarray(res.solution), direct,
                                    atol=3e-4, err_msg=backend)
+
+
+# ---------------------------------------------------------------------------
+# the early exit: the loop leaves once no column is active
+# ---------------------------------------------------------------------------
+#
+# _fixed_trip_pcg_* are frozen copies of the loops as they stood before the
+# early exit: `lax.scan`s over the whole trip count, with the x0 and
+# track_residuals arguments (classic body, identity allreduce). An
+# iteration in which no column is active changes no state and records
+# alpha = beta = 0, active = False, so leaving the loop there must give
+# every output bitwise — only the traversal count may differ.
+
+
+def _fixed_trip_pcg_standard(mvm, B, precond_solve, max_iters, min_iters, tol,
+                             x0, track_residuals):
+    dtype = B.dtype
+
+    def vdot(a, b):
+        return jnp.sum(a * b, axis=0)
+
+    if x0 is None:
+        u, r = jnp.zeros_like(B), B
+    else:
+        u, r = x0, B - mvm(x0)
+    z = precond_solve(r)
+    init = jnp.stack([jnp.sum(r * z, 0), jnp.sum(B * B, 0)])
+    rz, b_norm2 = init[0], jnp.maximum(init[1], 1e-30)
+    rz0 = rz
+    p = z
+
+    def body(carry, j):
+        u, r, z, p, rz = carry
+        Kp = mvm(p)
+        red1 = jnp.stack([jnp.sum(p * Kp, 0), jnp.sum(r * r, 0)])
+        pKp, r_norm2 = red1[0], red1[1]
+        rel = jnp.sqrt(r_norm2 / b_norm2)
+        active = (rel > tol) | (j < min_iters)
+        alpha = jnp.where(active, _golden_safe_div(rz, pKp), 0.0)
+        u = u + alpha * p
+        r = r - alpha * Kp
+        z_new = precond_solve(r)
+        rz_new = vdot(r, z_new)
+        beta = jnp.where(active, _golden_safe_div(rz_new, rz), 0.0)
+        p = jnp.where(active, z_new + beta * p, p)
+        z = jnp.where(active, z_new, z)
+        rz = jnp.where(active, rz_new, rz)
+        ys = (alpha.astype(dtype), beta.astype(dtype), active)
+        if track_residuals:
+            ys = ys + (rel.astype(dtype),)
+        return (u, r, z, p, rz), ys
+
+    (u, r, _, _, _), ys = jax.lax.scan(body, (u, r, z, p, rz),
+                                       jnp.arange(max_iters))
+    rel = jnp.sqrt(vdot(r, r) / b_norm2)
+    return (u, ys[0], ys[1], ys[2], rz0, rel, jnp.sum(ys[2], axis=0),
+            ys[3] if track_residuals else None)
+
+
+def _fixed_trip_pcg_pipelined(mvm, B, precond_solve, max_iters, min_iters,
+                              tol, x0, track_residuals):
+    dtype = B.dtype
+
+    def fused(r, u, w):
+        red = jnp.stack([jnp.sum(r * u, 0), jnp.sum(w * u, 0),
+                         jnp.sum(r * r, 0)])
+        return red[0], red[1], red[2]
+
+    if x0 is None:
+        x, r = jnp.zeros_like(B), B
+    else:
+        x, r = x0, B - mvm(x0)
+    b_norm2 = jnp.maximum(jnp.sum(B * B, 0), 1e-30)
+    u = precond_solve(r)
+    w = mvm(u)
+    gamma, delta, rr = fused(r, u, w)
+    rz0 = gamma
+    p = jnp.zeros_like(B)
+    s = jnp.zeros_like(B)
+    alpha_prev = jnp.ones_like(gamma)
+    gamma_prev = jnp.ones_like(gamma)
+
+    def body(carry, j):
+        x, r, u, w, p, s, gamma, delta, rr, gamma_prev, alpha_prev = carry
+        rel = jnp.sqrt(rr / b_norm2)
+        active = (rel > tol) | (j < min_iters)
+        first = j == 0
+        beta = jnp.where(first, 0.0, _golden_safe_div(gamma, gamma_prev))
+        denom = delta - beta * gamma / jnp.where(first, 1.0, alpha_prev)
+        alpha = jnp.where(active, _golden_safe_div(gamma, denom), 0.0)
+        beta = jnp.where(active, beta, 0.0)
+        p = jnp.where(active, u + beta * p, p)
+        s = jnp.where(active, w + beta * s, s)
+        x = x + alpha * p
+        r = r - alpha * s
+        u_new = precond_solve(r)
+        w_new = mvm(u_new)
+        gamma_new, delta_new, rr_new = fused(r, u_new, w_new)
+        u = jnp.where(active, u_new, u)
+        w = jnp.where(active, w_new, w)
+        gamma_prev_n = jnp.where(active, gamma, gamma_prev)
+        alpha_prev_n = jnp.where(active, alpha, alpha_prev)
+        gamma = jnp.where(active, gamma_new, gamma)
+        delta = jnp.where(active, delta_new, delta)
+        rr = jnp.where(active, rr_new, rr)
+        ys = (alpha.astype(dtype), beta.astype(dtype), active)
+        if track_residuals:
+            ys = ys + (rel.astype(dtype),)
+        return ((x, r, u, w, p, s, gamma, delta, rr, gamma_prev_n,
+                 alpha_prev_n), ys)
+
+    carry = (x, r, u, w, p, s, gamma, delta, rr, gamma_prev, alpha_prev)
+    (x, r, *_), ys = jax.lax.scan(body, carry, jnp.arange(max_iters))
+    rel = jnp.sqrt(jnp.sum(r * r, 0) / b_norm2)
+    return (x, ys[0], ys[1], ys[2], rz0, rel, jnp.sum(ys[2], axis=0),
+            ys[3] if track_residuals else None)
+
+
+_FIXED_TRIP = {"standard": _fixed_trip_pcg_standard,
+               "pipelined": _fixed_trip_pcg_pipelined}
+_OUTPUTS = ("solution", "alphas", "betas", "active", "rz0", "rel_residual",
+            "iterations", "residuals")
+
+
+def _exit_problem(rng, warm):
+    """A 3-column system whose columns converge at different iterations;
+    with a warm start, column 0 starts at its solution."""
+    X, params, Khat, mvm = _setup(rng, n=72, noise=0.4)
+    B = jnp.asarray(rng.normal(size=(72, 3)))
+    pre = make_preconditioner("matern32", X, params, 20)
+    x0 = None
+    if warm:
+        guess = rng.normal(size=(72, 3))
+        guess[:, 0] = np.asarray(jnp.linalg.solve(Khat, B[:, 0]))
+        x0 = jnp.asarray(guess)
+    return mvm, B, pre, x0
+
+
+def _trip_count(method, max_iters, warm):
+    """Traversals of the whole trip count: every iteration, the warm
+    start's residual and the pipelined loop's pre-loop MVM."""
+    return max_iters + warm + (method == "pipelined")
+
+
+def _expected_traversals(method, iterations, max_iters, warm):
+    """The standard loop learns that no column is active from the
+    iteration's own MVM, so it runs one body more than the most iterations
+    any column applied; the pipelined loop learns it before the MVM."""
+    most = int(np.max(np.asarray(iterations)))
+    bodies = min(most + 1, max_iters) if method == "standard" else most
+    return bodies + warm + (method == "pipelined")
+
+
+@pytest.mark.parametrize("track", [False, True])
+@pytest.mark.parametrize("warm", [False, True])
+@pytest.mark.parametrize("method", ["standard", "pipelined"])
+def test_early_exit_bitwise_matches_fixed_trip_loop(rng, method, warm, track):
+    """With max_iters far above what the columns need, the loop leaves
+    early and still gives every output of the whole trip count bitwise."""
+    mvm, B, pre, x0 = _exit_problem(rng, warm)
+    max_iters, min_iters, tol = 60, 3, 1e-2
+    res = pcg(mvm, B, pre.solve, max_iters=max_iters, min_iters=min_iters,
+              tol=tol, method=method, x0=x0, track_residuals=track)
+    want = _FIXED_TRIP[method](mvm, B, pre.solve, max_iters, min_iters, tol,
+                               x0, track)
+    got = (res.solution, res.alphas, res.betas, res.active, res.rz0,
+           res.rel_residual, res.iterations, res.residuals)
+    for g, w, name in zip(got, want, _OUTPUTS):
+        if w is None:
+            assert g is None, name
+            continue
+        assert np.array_equal(np.asarray(g), np.asarray(w)), name
+    if x0 is None and not track:
+        golden = _GOLDEN[method](mvm, B, pre.solve, max_iters, min_iters, tol)
+        for g, w, name in zip(got, golden, _OUTPUTS):
+            assert np.array_equal(np.asarray(g), np.asarray(w)), name
+    iters = np.asarray(res.iterations)
+    assert iters.min() < iters.max() < max_iters  # columns froze apart
+    assert int(res.traversals) == _expected_traversals(
+        method, iters, max_iters, warm)
+    assert int(res.traversals) < _trip_count(method, max_iters, warm)
+
+
+@pytest.mark.parametrize("track", [False, True])
+@pytest.mark.parametrize("warm", [False, True])
+@pytest.mark.parametrize("method", ["standard", "pipelined"])
+def test_early_exit_traversals_are_the_matvecs_run(rng, method, warm, track):
+    """`traversals` is what the matvec itself counts on the host."""
+    mvm0, B, pre, x0 = _exit_problem(rng, warm)
+    calls = []
+
+    def mvm(v):
+        jax.debug.callback(lambda: calls.append(1))
+        return mvm0(v)
+
+    max_iters = 60
+    res = jax.jit(lambda B, x0: pcg(
+        mvm, B, pre.solve, max_iters=max_iters, min_iters=3, tol=1e-2,
+        method=method, x0=x0, track_residuals=track))(B, x0)
+    jax.block_until_ready(res.solution)
+    expect = _expected_traversals(method, res.iterations, max_iters, warm)
+    assert int(res.traversals) == len(calls) == expect
+    assert expect < _trip_count(method, max_iters, warm)
+
+
+@pytest.mark.parametrize("track", [False, True])
+@pytest.mark.parametrize("warm", [False, True])
+@pytest.mark.parametrize("method", ["standard", "pipelined"])
+def test_unconverged_solve_runs_every_iteration(rng, method, warm, track):
+    """A tolerance no column reaches keeps every column active: the loop
+    runs all max_iters iterations, as the fixed trip count did."""
+    mvm, B, pre, x0 = _exit_problem(rng, warm)
+    if x0 is not None:
+        x0 = x0.at[:, 0].add(1.0)  # no column starts at its solution
+    max_iters = 7
+    res = pcg(mvm, B, pre.solve, max_iters=max_iters, min_iters=3,
+              tol=1e-14, method=method, x0=x0, track_residuals=track)
+    assert np.all(np.asarray(res.iterations) == max_iters)
+    assert np.all(np.asarray(res.active))
+    assert int(res.traversals) == _trip_count(method, max_iters, warm)
+    want = _FIXED_TRIP[method](mvm, B, pre.solve, max_iters, 3, 1e-14, x0,
+                               track)
+    got = (res.solution, res.alphas, res.betas, res.active, res.rz0,
+           res.rel_residual, res.iterations, res.residuals)
+    for g, w, name in zip(got, want, _OUTPUTS):
+        if w is not None:
+            assert np.array_equal(np.asarray(g), np.asarray(w)), name
